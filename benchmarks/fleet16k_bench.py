@@ -1,106 +1,59 @@
-"""Sharded-fleet scale axis: 16384 NetES agents on a simulated 8-device
-mesh (DESIGN.md §13).
+"""Sharded-fleet scale axis: 16384 NetES agents with the agent axis
+sharded over every device ``jax.devices()`` holds (DESIGN.md §13).
 
 The paper's thesis is that sparse topologies buy their learning
 performance *cheaply* — the communication cost argument only becomes
 real once the agent axis is physically partitioned and cross-shard
 edges cost actual collective traffic. This bench runs the
-``distributed/fleet_shard`` engine at N = 16384 over
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and gates three
-things per leg:
+``distributed/fleet_shard`` engine at N = 16384 on a mesh of all local
+devices (four chips on a v5e 2x2 host; on a CPU host, as many as
+``XLA_FLAGS=--xla_force_host_platform_device_count=<n>`` makes) and
+gates three things per leg:
 
 * **zero steady-state recompiles** — a warmed engine must replay its
-  scan chunk without a single XLA backend compile (counted via the jax
-  monitoring hook, same gate as ``fleet_bench``);
+  scan chunk without a single XLA backend compile (counted via
+  ``repro.obs.xla_watch``, same gate as ``fleet_bench``);
 * **exact per-shard wire bytes** — ``ShardedNetES.collective_bytes``
   derives payload/reward/broadcast bytes from the static shapes of the
   ppermute/all-gather operands the compiled program executes, so they
   are Python ints and gate with ``wire_bytes`` exact-match semantics.
-  The headline physics must hold: ER halo bytes < FC gather bytes at
-  matched update semantics, and the int8 wire codec (quantize(bits=8))
-  must shrink the ER halo payload ~4×;
+  On a mesh of more than one device the headline physics must hold: ER
+  halo bytes < FC gather bytes at matched update semantics, and the
+  int8 wire codec (quantize(bits=8)) must shrink the ER halo payload;
 * **steady-state median step time** (advisory until a like-hardware
   baseline is armed — see check_regression.py).
 
 A fourth entry, ``fleet.netes16384.shard_parity``, scores the
 shard-invariance contract at small N: the SAME seed must produce
-bit-identical trajectories on mesh sizes {1, 8} and the single-device
-solo oracle, for sparse/circulant/FC modes and the quantized channel.
+bit-identical trajectories on mesh sizes {1, n_dev} and the
+single-device solo oracle, for sparse/circulant/FC modes and the
+quantized channel.
 
-Everything jax runs in a SUBPROCESS so the forced 8-device host
-platform never leaks into the parent bench process (the other suites
-expect the default single-device CPU); results come back as one JSON
-line behind a sentinel prefix, mirroring ``tests/test_permute_mixing``.
+Everything runs in the bench's own process: a child process could not
+take a chip that this one already holds.
 """
 from __future__ import annotations
 
-import json
-import os
-import pathlib
-import subprocess
-import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from benchmarks import common, registry
-
-REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
+from repro.comm import channel as comm_channel
+from repro.core import netes, topology, topology_repr
+from repro.core.netes import NetESConfig
+from repro.distributed import fleet_shard
+from repro.obs.xla_watch import count_backend_compiles
 
 N_BIG = 16384
-N_DEV = 8
 DIM = 32
 # G(n, m) edge budget: m = 4n undirected edges → mean degree 8 (+ self
 # loop), the sparse-regime operating point the paper's 1000-agent ER
 # graphs sit in.
 EDGES_PER_NODE = 4
 CIRC_OFFSETS = (1, 2, 3, 4)
-
-_SENTINEL = "FLEET16K_RESULT "
-
-_SUBPROCESS_SCRIPT = r"""
-import json
-import sys
-import time
-
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import contextlib
-
-import numpy as np
-
-import jax
-import jax.numpy as jnp
-
-from repro.comm import channel as comm_channel
-from repro.core import netes, topology, topology_repr
-from repro.core.netes import NetESConfig
-from repro.distributed import fleet_shard
-
-KNOBS = json.loads(sys.argv[1])
-N, NDEV, D = KNOBS["n"], KNOBS["n_dev"], KNOBS["dim"]
-CHUNK, REPLAYS = KNOBS["chunk"], KNOBS["replays"]
-
-assert jax.device_count() >= NDEV, (
-    f"host platform has {jax.device_count()} devices, need {NDEV} — "
-    "XLA_FLAGS must be set before jax import")
-
-
-@contextlib.contextmanager
-def count_compiles():
-    # benchmarks/common.count_backend_compiles, inlined so the
-    # subprocess imports nothing outside repro + stdlib.
-    from jax._src import monitoring
-    counts = []
-
-    def cb(event, *a, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            counts.append(event)
-
-    monitoring.register_event_duration_secs_listener(cb)
-    try:
-        yield counts
-    finally:
-        monitoring._unregister_event_duration_listener_by_callback(cb)
 
 
 def reward_fn(params, key):
@@ -143,7 +96,7 @@ def er_sparse_topology(n, edges_per_node, seed):
 
 
 # ---- shard-invariance parity at small N (the tentpole contract) -------
-def parity_check():
+def parity_check(n_dev):
     n_small, d_small, iters = 257, 16, 5
     cfg = NetESConfig(alpha=0.05, sigma=0.1, p_broadcast=0.5)
     state0 = netes.init_state(jax.random.PRNGKey(0), n_small, d_small)
@@ -161,7 +114,7 @@ def parity_check():
     out = {}
     for name, (topo, chan) in legs.items():
         runs = {}
-        for ndev in (None, 1, NDEV):
+        for ndev in (None, 1, n_dev):
             mesh = None if ndev is None else fleet_shard.build_mesh(ndev)
             eng = fleet_shard.ShardedNetES(topo, reward_fn, cfg,
                                            mesh=mesh, channel=chan)
@@ -173,29 +126,29 @@ def parity_check():
         ref = runs[None]
         ok = all(
             all(np.array_equal(np.asarray(x), np.asarray(y))
-                for x, y in zip(runs[nd], ref))
-            for nd in (1, NDEV))
+                for x, y in zip(runs[nd], ref, strict=True))
+            for nd in (1, n_dev))
         out[name] = bool(ok)
     return out
 
 
 # ---- the 16384-agent legs ---------------------------------------------
-def timed_leg(topo, chan):
+def timed_leg(topo, chan, n_dev, chunk, replays):
     cfg = NetESConfig(alpha=0.05, sigma=0.1, p_broadcast=0.5)
-    mesh = fleet_shard.build_mesh(NDEV)
+    mesh = fleet_shard.build_mesh(n_dev)
     eng = fleet_shard.ShardedNetES(topo, reward_fn, cfg, mesh=mesh,
                                    channel=chan)
-    state0 = netes.init_state(jax.random.PRNGKey(1), N, D)
+    state0 = netes.init_state(jax.random.PRNGKey(1), N_BIG, DIM)
     cs = chan.init(state0.thetas) if chan is not None else None
 
-    jax.block_until_ready(eng.run(state0, CHUNK, chan_state=cs))  # warmup
+    jax.block_until_ready(eng.run(state0, chunk, chan_state=cs))  # warmup
     steps = []
-    with count_compiles() as compiles:
-        for _ in range(REPLAYS):
+    with count_backend_compiles() as compiles:
+        for _ in range(replays):
             t0 = time.perf_counter()
-            jax.block_until_ready(eng.run(state0, CHUNK, chan_state=cs))
-            steps.append((time.perf_counter() - t0) / CHUNK)
-    bytes_ = eng.collective_bytes(D)
+            jax.block_until_ready(eng.run(state0, chunk, chan_state=cs))
+            steps.append((time.perf_counter() - t0) / chunk)
+    bytes_ = eng.collective_bytes(DIM)
     return {"step_s": float(np.median(steps)),
             "step_s_min": float(min(steps)),
             "step_s_max": float(max(steps)),
@@ -204,77 +157,39 @@ def timed_leg(topo, chan):
             **{k: int(v) for k, v in bytes_.items()}}
 
 
-parity = parity_check()
-
-er_topo = er_sparse_topology(N, KNOBS["edges_per_node"], seed=7)
-q8 = comm_channel.compile_channel("quantize(bits=8)", N)
-circ = topology_repr.Topology(
-    kind="circulant", n=N,
-    deg=jnp.full((N,), 2 * len(KNOBS["circ_offsets"]) + 1, jnp.float32),
-    offsets=tuple(KNOBS["circ_offsets"]))
-
-legs = {
-    "er_sparse": timed_leg(er_topo, None),
-    "er_sparse_q8": timed_leg(er_topo, q8),
-    "circulant": timed_leg(circ, None),
-    "fc": timed_leg(fleet_shard.FullyConnected(N), None),
-}
-
-sys.stdout.write(KNOBS["sentinel"] + json.dumps(
-    {"parity": parity, "legs": legs,
-     "device_count": jax.device_count()}) + "\n")
-"""
-
-
-def _spawn(knobs: dict, timeout_s: int) -> dict:
-    """Run the jax work in a clean subprocess and parse the sentinel
-    JSON line (the forced 8-device platform must not leak into this
-    process's jax)."""
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(REPO_ROOT / "src"), str(REPO_ROOT)]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    res = subprocess.run(
-        [sys.executable, "-c", _SUBPROCESS_SCRIPT, json.dumps(knobs)],
-        capture_output=True, text=True, timeout=timeout_s, env=env)
-    payload = None
-    for line in res.stdout.splitlines():
-        if line.startswith(_SENTINEL):
-            payload = json.loads(line[len(_SENTINEL):])
-    if res.returncode != 0 or payload is None:
-        raise RuntimeError(
-            f"fleet16k subprocess failed (rc={res.returncode}):\n"
-            f"{res.stdout[-2000:]}\n{res.stderr[-4000:]}")
-    return payload
-
-
 def run(quick: bool = False):
-    knobs = {
-        "n": N_BIG, "n_dev": N_DEV, "dim": DIM,
-        "edges_per_node": EDGES_PER_NODE,
-        "circ_offsets": list(CIRC_OFFSETS),
-        "chunk": 2 if quick else 4,
-        "replays": 2 if quick else 3,
-        "sentinel": _SENTINEL,
-    }
-    payload = _spawn(knobs, timeout_s=600 if quick else 1200)
-
-    parity = payload["parity"]
+    n_dev = jax.device_count()
+    chunk, replays = (2, 2) if quick else (4, 3)
+    parity = parity_check(n_dev)
     assert all(parity.values()), \
         f"shard-invariance parity failed: {parity}"
 
-    legs = payload["legs"]
+    er_topo = er_sparse_topology(N_BIG, EDGES_PER_NODE, seed=7)
+    q8 = comm_channel.compile_channel("quantize(bits=8)", N_BIG)
+    circ = topology_repr.Topology(
+        kind="circulant", n=N_BIG,
+        deg=jnp.full((N_BIG,), 2 * len(CIRC_OFFSETS) + 1, jnp.float32),
+        offsets=CIRC_OFFSETS)
+    legs = {name: timed_leg(topo, chan, n_dev, chunk, replays)
+            for name, topo, chan in (
+                ("er_sparse", er_topo, None),
+                ("er_sparse_q8", er_topo, q8),
+                ("circulant", circ, None),
+                ("fc", fleet_shard.FullyConnected(N_BIG), None))}
+
     for name, leg in legs.items():
         assert leg["timed_compiles"] == 0, \
             f"{name}: {leg['timed_compiles']} steady-state recompile(s)"
-    # The paper's communication argument, measured where bytes move:
-    # sparse halo traffic must undercut the FC gather, and the int8 wire
-    # codec must undercut raw f32 halo rows.
-    assert legs["er_sparse"]["payload_bytes"] < legs["fc"]["payload_bytes"]
-    assert (legs["er_sparse_q8"]["payload_bytes"]
-            < legs["er_sparse"]["payload_bytes"])
-    assert (legs["circulant"]["payload_bytes"]
-            < legs["er_sparse"]["payload_bytes"])
+    if n_dev > 1:
+        # The paper's communication argument, measured where bytes move:
+        # sparse halo traffic must undercut the FC gather, and the int8
+        # wire codec must undercut raw f32 halo rows.
+        assert (legs["er_sparse"]["payload_bytes"]
+                < legs["fc"]["payload_bytes"])
+        assert (legs["er_sparse_q8"]["payload_bytes"]
+                < legs["er_sparse"]["payload_bytes"])
+        assert (legs["circulant"]["payload_bytes"]
+                < legs["er_sparse"]["payload_bytes"])
 
     entries = []
     for name, leg in legs.items():
@@ -286,8 +201,8 @@ def run(quick: bool = False):
             name=ename,
             wall_s=leg["step_s"],
             wire_bytes=leg["total_bytes"],
-            extra={"n": N_BIG, "dim": DIM, "n_dev": N_DEV,
-                   "chunk": knobs["chunk"], "replays": knobs["replays"],
+            extra={"n": N_BIG, "dim": DIM, "n_dev": n_dev,
+                   "chunk": chunk, "replays": replays,
                    "plan_mode": leg["plan_mode"],
                    "payload_rows": leg["payload_rows"],
                    "payload_bytes": leg["payload_bytes"],
@@ -300,8 +215,8 @@ def run(quick: bool = False):
         name=f"fleet.netes{N_BIG}.shard_parity",
         eval_score=float(all(parity.values())),
         extra={"legs": parity, "n": 257,
-               "mesh_sizes": [1, N_DEV],
-               "device_count": payload["device_count"]}))
+               "mesh_sizes": sorted({1, n_dev}),
+               "device_count": n_dev}))
     return entries
 
 

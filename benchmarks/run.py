@@ -31,6 +31,7 @@ for _p in (str(_ROOT), str(_ROOT / "src")):
 import importlib                                              # noqa: E402
 
 from benchmarks import registry                               # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache   # noqa: E402
 
 # Importing the suite modules populates the registry.
 for _mod in ("fig2a_families", "fig2b_size_sweep", "fig3a_broadcast",
@@ -63,6 +64,7 @@ def main(argv=None) -> int:
                   f"profiles={','.join(b.profiles)}")
         return 0
 
+    enable_compile_cache()
     only = args.only.split(",") if args.only else None
     print("name,us_per_call,derived")
     t0 = time.time()

@@ -36,7 +36,7 @@ from __future__ import annotations
 from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from .findings import Finding
 from .registry import EntryPoint, iter_entry_points

@@ -23,9 +23,8 @@ One form covers every quantize mode the channel speaks
 ``decode`` is deliberately uniform across bits — ``codes · scale`` —
 which is what makes it a *block* function: it applies unchanged to any
 aligned slab of codes + scales, so a Pallas kernel can inline it per
-tile (``kernels/netes_fused_mixing``) and the XLA twin can fold the
-scale into the contraction weights. ``comm.channel`` re-exports it as
-the codec's decode.
+tile (``kernels/netes_fused_mixing``) exactly as XLA runs it on the
+whole array. ``comm.channel`` re-exports it as the codec's decode.
 
 This module is import-leaf (jax only): ``core.topology_repr`` dispatches
 on ``WirePayload``, ``comm.channel`` encodes into it, and the kernels

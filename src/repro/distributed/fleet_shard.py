@@ -55,7 +55,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.comm import channel as comm_channel
@@ -521,7 +520,11 @@ class ShardedNetES:
                                           shaped.astype(buf.dtype), buf)
             return mixed, wsum, operands["deg"], cs, chan_metrics
         # full: rank-1 — Σ_i R̃_i·wire_i is one replicated (D,) vector.
-        svec = shaped.astype(buf.dtype) @ buf
+        # A gemv's accumulation order is the backend's choice and varies
+        # with the program around it; rounded products summed by one
+        # reduction give the same bits on every mesh size.
+        svec = jax.lax.optimization_barrier(
+            shaped.astype(buf.dtype)[:, None] * buf).sum(axis=0)
         wsum_scalar = shaped.sum()
         mixed = jnp.broadcast_to(svec, th.shape)
         wsum = jnp.broadcast_to(wsum_scalar, (n_loc,))
@@ -549,15 +552,27 @@ class ShardedNetES:
         # size adds bit-identical values (shard-invariance contract).
         s_eps = jax.lax.optimization_barrier(cfg.sigma * eps)
         pert_pos = th + s_eps
+
+        def rewards(params):
+            # A reward with per-row keys (envs.rollout) gets agent g's
+            # key split(k_eval, N)[g] — what the whole population's call
+            # hands it — so episodes do not depend on the mesh size
+            # (split(k, n_pad)[:N] == split(k, N)).
+            rowwise = getattr(self.reward_fn, "rowwise", None)
+            if rowwise is None:
+                return self.reward_fn(params, k_eval)
+            keys = jnp.take(jax.random.split(k_eval, n_pad), gid, axis=0)
+            return rowwise(params, keys)
+
         if cfg.antithetic:
             pert_neg = th - s_eps
-            r_pos = ops.all_gather(self.reward_fn(pert_pos, k_eval))[:n]
-            r_neg = ops.all_gather(self.reward_fn(pert_neg, k_eval))[:n]
+            r_pos = ops.all_gather(rewards(pert_pos))[:n]
+            r_neg = ops.all_gather(rewards(pert_neg))[:n]
             raw = jnp.concatenate([r_pos, r_neg])
             shaped_all = netes.shape_fitness(raw, cfg.fitness_shaping)
             shaped = shaped_all[:n] - shaped_all[n:]
         else:
-            raw = ops.all_gather(self.reward_fn(pert_pos, k_eval))[:n]
+            raw = ops.all_gather(rewards(pert_pos))[:n]
             shaped = netes.shape_fitness(raw, cfg.fitness_shaping)
         shaped_pad = jnp.pad(shaped, (0, n_pad - n))
 
@@ -616,6 +631,10 @@ class ShardedNetES:
             s2 = ops.psum((valid[:, None] * x * x).sum(axis=0))
             return ((s2 / n) - (s1 / n) ** 2).sum()
 
+        # the gathered (N,) rewards are fused into the reductions below
+        # differently by each program shape; materialize them first so
+        # every mesh size (and solo) sums them in the same order
+        raw = jax.lax.optimization_barrier(raw)
         metrics = {
             "reward_mean": raw.mean(),
             "reward_max": raw.max(),
@@ -692,14 +711,14 @@ class ShardedNetES:
         def run_impl(th, key, step, best_r, best_th, operands, cs, ss,
                      mst, num_iters):
             repl = lambda tree: jax.tree.map(lambda _: P(), tree)
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda *a: local_run(ops, *a, num_iters),
                 mesh=self.mesh,
                 in_specs=(P(axis, None), P(), P(), P(), P(), opspec,
                           repl(cs), repl(ss), repl(mst)),
                 out_specs=(P(axis, None), P(), P(), P(), P(), repl(cs),
                            repl(ss), repl(mst), P()),
-                check_rep=False)
+                check_vma=False)
             return fn(th, key, step, best_r, best_th, operands, cs, ss,
                       mst)
 

@@ -25,7 +25,6 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.topology_repr import Topology, signed_offsets  # noqa: F401
@@ -96,7 +95,7 @@ def make_permute_mixing(mesh: Mesh, axis: str, offsets: Sequence[int],
             acc = acc + weights[j, src_idx] * recv
         return acc
 
-    mixed = shard_map(
+    mixed = jax.shard_map(
         local_mix, mesh=mesh,
         in_specs=(P(None, None), P(axis, None)),
         out_specs=P(axis, None))
@@ -123,9 +122,9 @@ def make_allgather_mixing(mesh: Mesh, axis: str, channel=None):
                                   tiled=True)                   # (N, D)
         return (weights[j] @ full)[None]
 
-    return shard_map(local_mix, mesh=mesh,
-                     in_specs=(P(None, None), P(axis, None)),
-                     out_specs=P(axis, None))
+    return jax.shard_map(local_mix, mesh=mesh,
+                         in_specs=(P(None, None), P(axis, None)),
+                         out_specs=P(axis, None))
 
 
 def make_sparse_gather_mixing(mesh: Mesh, axis: str, topo: Topology,
@@ -157,9 +156,9 @@ def make_sparse_gather_mixing(mesh: Mesh, axis: str, topo: Topology,
         w = weights[j, cols] * valid                    # (K,)
         return (w @ jnp.take(full, cols, axis=0))[None]
 
-    return shard_map(local_mix, mesh=mesh,
-                     in_specs=(P(None, None), P(axis, None)),
-                     out_specs=P(axis, None))
+    return jax.shard_map(local_mix, mesh=mesh,
+                         in_specs=(P(None, None), P(axis, None)),
+                         out_specs=P(axis, None))
 
 
 def make_topology_mixing(mesh: Mesh, axis: str, topo: Topology,
@@ -227,9 +226,9 @@ def make_rotating_permute_mixing(mesh: Mesh, axis: str,
     def local_mix(weights, theta, t):
         return jax.lax.switch(t % cycle, branches, weights, theta)
 
-    return shard_map(local_mix, mesh=mesh,
-                     in_specs=(P(None, None), P(axis, None), P()),
-                     out_specs=P(axis, None))
+    return jax.shard_map(local_mix, mesh=mesh,
+                         in_specs=(P(None, None), P(axis, None), P()),
+                         out_specs=P(axis, None))
 
 
 # ---------------------------------------------------------------------------

@@ -46,11 +46,17 @@ def make_env_reward_fn(env, policy: MLPPolicy,
         rets = jax.vmap(partial(episode_return, env, policy, theta))(keys)
         return rets.mean()
 
-    def reward_fn(params: jax.Array, key: jax.Array) -> jax.Array:
-        m = params.shape[0]
-        keys = jax.random.split(key, m)
+    def rowwise(params: jax.Array, keys: jax.Array) -> jax.Array:
         return jax.vmap(single)(params, keys)
 
+    def reward_fn(params: jax.Array, key: jax.Array) -> jax.Array:
+        return rowwise(params, jax.random.split(key, params.shape[0]))
+
+    # Row m's episode key is split(key, M)[m]. A caller that evaluates
+    # a slice of the population (one shard of a mesh) hands ``rowwise``
+    # the keys of its rows, so each agent sees the same episode at any
+    # mesh size (distributed/fleet_shard.py).
+    reward_fn.rowwise = rowwise
     return reward_fn
 
 

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels for the compute hot spots, each with a jnp oracle in
+# ref.py. The platform picks the lowering: compiled on TPU, interpreted
+# elsewhere.

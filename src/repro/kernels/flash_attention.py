@@ -30,22 +30,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
                   window: int, chunk: int, block_q: int, block_k: int,
                   seq_k: int, seq_k_valid: int):
     qi = pl.program_id(1)
-    # NOTE: literal-int ref indices (q_ref[0]) break pallas interpret on
-    # jax 0.4.37 (NDIndexer requires Slice / shaped scalars) — index with
-    # scalar arrays / load the whole block instead, throughout this file.
-    zero = jnp.int32(0)
     q = q_ref[...][0].astype(jnp.float32)       # (block_q, G, hd)
     g, hd = q.shape[1], q.shape[2]
     q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
 
     def body(ki, carry):
         acc, m, l = carry
-        k_tile = pl.load(
-            k_ref, (zero, pl.dslice(ki * block_k, block_k), slice(None))
-        ).astype(jnp.float32)                   # (block_k, hd)
-        v_tile = pl.load(
-            v_ref, (zero, pl.dslice(ki * block_k, block_k), slice(None))
-        ).astype(jnp.float32)                   # (block_k, hd)
+        k_tile = k_ref[0, pl.ds(ki * block_k, block_k)].astype(
+            jnp.float32)                        # (block_k, hd)
+        v_tile = v_ref[0, pl.ds(ki * block_k, block_k)].astype(
+            jnp.float32)                        # (block_k, hd)
         k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
         s = jnp.einsum("qgd,kd->gqk", q, k_tile,
                        preferred_element_type=jnp.float32) * scale

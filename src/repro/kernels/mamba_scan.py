@@ -23,14 +23,10 @@ TILE_D = 256
 
 def _scan_kernel(decay_ref, drive_ref, h_ref, *, seq: int):
     td, n = decay_ref.shape[2], decay_ref.shape[3]
-    # scalar-array index: literal ints break pallas interpret on jax 0.4.37
-    zero = jnp.int32(0)
 
     def body(t, h):
-        dec = pl.load(decay_ref, (zero, t, slice(None), slice(None)))
-        drv = pl.load(drive_ref, (zero, t, slice(None), slice(None)))
-        h = dec * h + drv
-        pl.store(h_ref, (zero, t, slice(None), slice(None)), h)
+        h = decay_ref[0, t] * h + drive_ref[0, t]
+        h_ref[0, t] = h
         return h
 
     h0 = jnp.zeros((td, n), jnp.float32)

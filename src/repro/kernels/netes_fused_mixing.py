@@ -2,40 +2,27 @@
 
 The unfused channel hot path runs three passes — decode the quantized
 payload to f32, apply the live-link mask, contract the weighted neighbor
-sum — each materializing an (N, K, D)-scale intermediate. This kernel
-does all three in ONE pass over the block-sparse (N, K_max) neighbor
-layout, reading the int8 wire codes (``core.wire_format.WirePayload``)
-directly, so the decoded f32 payload never exists and the gathered
-operand is 4× narrower than the f32 path:
+sum — each materializing an (N, K, D)-scale intermediate. The fused
+neighbor sum takes the int8 wire codes (``core.wire_format.WirePayload``)
+and does all three over the block-sparse (N, K_max) neighbor layout, so
+the (N, K, D) gather never exists:
 
-    out[j] = Σ_k m_jk · em_jk · coeff[i_jk] · (codes[i_jk] · scale[i_jk])
+    out[j] = Σ_k (m_jk · em_jk · coeff[i_jk]) · (codes[i_jk] · scale[i_jk])
 
-The per-source decode ``scale`` folds into the per-slot scalar weight
-once, up front — ``ws_jk = m_jk · em_jk · coeff[i_jk] · scale[i_jk]``,
-an (N, K) f32 array — and each accumulation step is then literally the
-codec's decode block function applied to a gathered int8 slab with the
-folded scale: ``wire_format.decode(codes[i_jk], ws_jk)``
-(``comm.channel.decode_block`` re-exports that exact function). Both
-backends below share this association, so they agree to roundoff.
+with every product rounded in the order the unfused path rounds it, so
+the two agree bit for bit.
 
-Two lowerings behind one entry point:
+``fused_neighbor_sum`` is XLA on every platform: a Pallas lowering with
+an in-kernel row gather is refused by the TPU compiler (``dynamic_slice``
+has no Mosaic lowering), so a compiled gather kernel needs its own
+design and a measured cell on each side before it comes back.
 
-* ``backend="pallas"`` — the TPU mapping, same schedule as
-  ``netes_sparse_mixing``: grid over D tiles; idx/ws resident in VMEM; a
-  ``fori_loop`` over neighbor slots performs one int8 row-gather +
-  decode + accumulate per step, keeping transients at one (N, TILE_D)
-  f32 slab. ``interpret=True`` (the CPU-CI default) validates the exact
-  kernel program against the jnp oracle.
-* ``backend="xla"`` — the same algebra as straight-line jnp (int8
-  gathers, ×4-unrolled slot loop). This is the production path on
-  non-TPU backends, where interpret-mode Pallas inside a training scan
-  would be orders of magnitude slower than XLA's native lowering.
-
-``backend="auto"`` resolves to pallas on TPU and xla elsewhere;
-``REPRO_FUSED_BACKEND`` overrides (CI pins ``pallas`` + interpret for
-the tier-1 kernel gate). The broadcast-best payload path gets the same
-treatment in ``fused_broadcast_select``: decode-where-flagged in one
-pass instead of decode → broadcast → select.
+``fused_broadcast_select`` (decode-where-flagged in one pass instead of
+decode → broadcast → select) has two lowerings. ``backend="auto"`` picks
+by platform: the Pallas kernel, compiled, on TPU; the XLA lowering
+elsewhere. An explicit ``backend="pallas"`` off TPU runs the same kernel
+program in interpret mode — how the CPU tests check it against its
+oracle.
 
 Oracles: ``ref.fused_neighbor_sum_ref`` / ``ref.broadcast_select_ref``
 (decode-then-contract, (N, K, D) materialized — the correctness
@@ -44,7 +31,6 @@ contract the fusion is tested against).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -52,16 +38,22 @@ from jax.experimental import pallas as pl
 
 from repro.core import wire_format
 
+# Broadcast-select block: (TILE_N, TILE_D) f32 is 1 MiB, so the θ input
+# and output, double-buffered, stay well inside v5e's scoped VMEM at any
+# N (a block spanning all of N is refused at N=16384, D=4481).
+TILE_N = 512
 TILE_D = 512
 
 BACKENDS = ("pallas", "xla")
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _resolve_backend(backend: str) -> str:
     if backend == "auto":
-        backend = os.environ.get("REPRO_FUSED_BACKEND", "auto")
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+        backend = "pallas" if _on_tpu() else "xla"
     if backend not in BACKENDS:
         raise ValueError(f"unknown fused backend {backend!r}; "
                          f"available: {BACKENDS + ('auto',)}")
@@ -69,110 +61,53 @@ def _resolve_backend(backend: str) -> str:
 
 
 def _resolve_interpret(interpret) -> bool:
-    # Repo convention: Pallas kernels interpret by default off-TPU.
-    return jax.default_backend() != "tpu" if interpret is None \
-        else bool(interpret)
-
-
-def _folded_weights(neighbor_idx, neighbor_mask, coeff, scale, edge_mask):
-    """(N, K) f32 per-slot weights with the decode scale folded in.
-    Weight formation stays in f32 (the coeff dtype) exactly like the
-    unfused sparse path in ``topology_repr.weighted_neighbor_sum``."""
-    w = neighbor_mask * jnp.take(coeff.astype(jnp.float32), neighbor_idx)
-    if edge_mask is not None:
-        w = w * edge_mask
-    return w * jnp.take(scale.reshape(-1), neighbor_idx)
+    # Pallas compiles on TPU and interprets everywhere else.
+    return not _on_tpu() if interpret is None else bool(interpret)
 
 
 # ---------------------------------------------------------------------------
 # fused neighbor sum
 # ---------------------------------------------------------------------------
 
-def _fused_neighbor_sum_kernel(idx_ref, ws_ref, codes_ref, out_ref):
-    idx = idx_ref[...]                       # (N, K) i32 — resident
-    ws = ws_ref[...]                         # (N, K) f32 — folded weights
-    codes = codes_ref[...]                   # (N, TILE_D) i8 slab
-    k_max = idx.shape[1]
-
-    def body(c, acc):
-        col = idx[:, c]                      # (N,) source of each receiver
-        # the codec decode, inlined per gathered block, with the scale
-        # already folded into the slot weight
-        return acc + wire_format.decode(jnp.take(codes, col, axis=0),
-                                        ws[:, c, None])
-
-    acc = jax.lax.fori_loop(0, k_max, body,
-                            jnp.zeros(codes.shape, jnp.float32))
-    out_ref[...] = acc.astype(out_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("out_dtype", "tile_d", "interpret", "backend"))
+@functools.partial(jax.jit, static_argnames=("out_dtype",))
 def fused_neighbor_sum(neighbor_idx: jax.Array, neighbor_mask: jax.Array,
                        coeff: jax.Array, codes: jax.Array,
                        scale: jax.Array, edge_mask=None, *,
-                       out_dtype=jnp.float32, tile_d: int = TILE_D,
-                       interpret=None, backend: str = "auto") -> jax.Array:
+                       out_dtype=jnp.float32) -> jax.Array:
     """``out_j = Σ_k mask_jk · em_jk · coeff_{i_jk} · codes_{i_jk} ·
     scale_{i_jk}`` — Eq. 3's neighbor contraction straight off the wire.
 
     neighbor_idx (N, K_max) int32; neighbor_mask / edge_mask (N, K_max);
     coeff (N,) f32; codes (N, D) int8; scale (N, 1) f32 (per-message
-    decode scale). Returns (N, D) in ``out_dtype``. D is padded to the
-    tile internally (pallas backend).
+    decode scale). Returns (N, D) in ``out_dtype``.
+
+    One decode of the wire codes up front (``wire_format.decode``, one
+    (N, D) f32 slab), then the same ×4-unrolled slot loop and the same
+    weight formation as the f32 sparse path in
+    ``topology_repr.weighted_neighbor_sum``. XLA:CPU has no fused
+    int8-gather·convert·fma, so a per-slot decode measured SLOWER than
+    one up-front pass.
     """
-    backend = _resolve_backend(backend)
-    ws = _folded_weights(neighbor_idx, neighbor_mask, coeff, scale,
-                         edge_mask)
-
-    if backend == "xla":
-        # One value-exact widening of the wire codes (int8 → f32 is
-        # lossless; the per-message decode SCALE stays folded in ``ws``),
-        # then the same slot loop as the f32 sparse path — the decoded-
-        # message (N, D) slab and the (N, K, D) gather never exist.
-        # XLA:CPU has no fused int8-gather·convert·fma, so keeping the
-        # codes int8 here costs a per-slot convert that measures SLOWER
-        # than one up-front cast; the int8-resident loop lives in the
-        # Pallas lowering.
-        values = codes.astype(jnp.float32)
-        idx = neighbor_idx
-        k_max = idx.shape[1]
-
-        def one(c, acc):
-            col = idx[:, c]
-            return acc + ws[:, c, None] * jnp.take(values, col, axis=0)
-
-        acc = jnp.zeros(codes.shape, jnp.float32)
-        k4 = k_max - k_max % 4
-        if k4:
-            def body(kk, a):
-                for u in range(4):
-                    a = one(kk * 4 + u, a)
-                return a
-            acc = jax.lax.fori_loop(0, k4 // 4, body, acc)
-        for c in range(k4, k_max):
-            acc = one(c, acc)
-        return acc.astype(out_dtype)
-
-    n, d = codes.shape
+    mask = neighbor_mask if edge_mask is None else neighbor_mask * edge_mask
+    ws = mask * jnp.take(coeff.astype(jnp.float32), neighbor_idx)
+    values = wire_format.decode(codes, scale)
     k_max = neighbor_idx.shape[1]
-    d_pad = -(-d // tile_d) * tile_d
-    codes_p = jnp.pad(codes, ((0, 0), (0, d_pad - d)))
-    grid = (d_pad // tile_d,)
-    out = pl.pallas_call(
-        _fused_neighbor_sum_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, k_max), lambda i: (0, 0)),   # idx: resident
-            pl.BlockSpec((n, k_max), lambda i: (0, 0)),   # ws: resident
-            pl.BlockSpec((n, tile_d), lambda i: (0, i)),  # codes slab
-        ],
-        out_specs=pl.BlockSpec((n, tile_d), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, d_pad), out_dtype),
-        interpret=_resolve_interpret(interpret),
-    )(neighbor_idx.astype(jnp.int32), ws.astype(jnp.float32), codes_p)
-    return out[:, :d]
+
+    def one(c, acc):
+        col = neighbor_idx[:, c]
+        return acc + ws[:, c, None] * jnp.take(values, col, axis=0)
+
+    acc = jnp.zeros(codes.shape, jnp.float32)
+    k4 = k_max - k_max % 4
+    if k4:
+        def body(kk, a):
+            for u in range(4):
+                a = one(kk * 4 + u, a)
+            return a
+        acc = jax.lax.fori_loop(0, k4 // 4, body, acc)
+    for c in range(k4, k_max):
+        acc = one(c, acc)
+    return acc.astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -182,23 +117,24 @@ def fused_neighbor_sum(neighbor_idx: jax.Array, neighbor_mask: jax.Array,
 def _broadcast_select_kernel(flag_ref, scale_ref, codes_ref, theta_ref,
                              out_ref):
     flag = flag_ref[0, 0]
-    theta = theta_ref[...]                   # (N, TILE_D)
+    theta = theta_ref[...]                   # (TILE_N, TILE_D)
     dec = wire_format.decode(codes_ref[...], scale_ref[...])  # (1, TILE_D)
     out_ref[...] = jnp.where(flag != 0, dec.astype(theta.dtype), theta)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("tile_d", "interpret", "backend"))
+@functools.partial(jax.jit, static_argnames=("interpret", "backend"))
 def fused_broadcast_select(codes: jax.Array, scale: jax.Array,
                            do_broadcast: jax.Array, thetas: jax.Array, *,
-                           tile_d: int = TILE_D, interpret=None,
+                           interpret=None,
                            backend: str = "auto") -> jax.Array:
     """``where(do_broadcast, decode(codes, scale), thetas)`` in one pass —
     every agent adopts the quantized broadcast-best payload without a
     decoded (D,) + broadcast (N, D) intermediate round-trip.
 
     codes (D,) int8; scale (1,) f32; do_broadcast scalar bool;
-    thetas (N, D). Returns (N, D) in thetas' dtype.
+    thetas (N, D). Returns (N, D) in thetas' dtype. The Pallas grid tiles
+    both axes; a ragged edge block is masked by Pallas, so nothing is
+    padded or cropped around the kernel.
     """
     backend = _resolve_backend(backend)
     if backend == "xla":
@@ -206,25 +142,24 @@ def fused_broadcast_select(codes: jax.Array, scale: jax.Array,
         return jnp.where(do_broadcast, dec[None, :], thetas)
 
     n, d = thetas.shape
-    d_pad = -(-d // tile_d) * tile_d
-    codes_p = jnp.pad(codes, (0, d_pad - d)).reshape(1, d_pad)
-    thetas_p = jnp.pad(thetas, ((0, 0), (0, d_pad - d)))
+    # a block either spans its whole axis or is (8, 128)-aligned
+    tn = min(n, TILE_N)
+    td = min(d, TILE_D)
     flag = do_broadcast.astype(jnp.int32).reshape(1, 1)
-    grid = (d_pad // tile_d,)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _broadcast_select_kernel,
-        grid=grid,
+        grid=(pl.cdiv(n, tn), pl.cdiv(d, td)),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),       # flag
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),       # scale
-            pl.BlockSpec((1, tile_d), lambda i: (0, i)),  # codes slab
-            pl.BlockSpec((n, tile_d), lambda i: (0, i)),  # θ slab
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),       # flag
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),       # scale
+            pl.BlockSpec((1, td), lambda i, j: (0, j)),      # codes slab
+            pl.BlockSpec((tn, td), lambda i, j: (i, j)),     # θ block
         ],
-        out_specs=pl.BlockSpec((n, tile_d), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, d_pad), thetas.dtype),
+        out_specs=pl.BlockSpec((tn, td), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((n, d), thetas.dtype),
         interpret=_resolve_interpret(interpret),
-    )(flag, scale.reshape(1, 1).astype(jnp.float32), codes_p, thetas_p)
-    return out[:, :d]
+    )(flag, scale.reshape(1, 1).astype(jnp.float32), codes.reshape(1, d),
+      thetas)
 
 
 # ---------------------------------------------------------------------------
@@ -232,11 +167,12 @@ def fused_broadcast_select(codes: jax.Array, scale: jax.Array,
 # ---------------------------------------------------------------------------
 
 def analysis_entry_points():
-    """Contract-linter entry points for both fused wire kernels, pallas
-    (interpret) and XLA lowerings. Deliberately NOT under the fma-seam
-    contract: the XLA slot loop's unguarded mul→add is shape-uniform by
-    construction (the whole (N, D) slab lives in one program), so FMA
-    contraction cannot break cross-shard parity here."""
+    """Contract-linter entry points for both fused wire kernels (the
+    broadcast select in its Pallas lowering, interpreted). Deliberately
+    NOT under the fma-seam contract: the XLA slot loop's unguarded
+    mul→add is shape-uniform by construction (the whole (N, D) slab
+    lives in one program), so FMA contraction cannot break cross-shard
+    parity here."""
     from repro.analysis.registry import EntryPoint
 
     def _wire_args(n=8, k=4, d=16):
@@ -246,13 +182,9 @@ def analysis_entry_points():
                 jnp.zeros((n, d), jnp.int8),       # codes
                 jnp.ones((n, 1), jnp.float32))     # scale
 
-    def _build_neighbor_sum(backend):
-        def build():
-            fn = functools.partial(fused_neighbor_sum,
-                                   out_dtype=jnp.float32,
-                                   interpret=True, backend=backend)
-            return fn, _wire_args(), {}
-        return build
+    def build_neighbor_sum():
+        fn = functools.partial(fused_neighbor_sum, out_dtype=jnp.float32)
+        return fn, _wire_args(), {}
 
     def build_broadcast_select():
         d, n = 16, 8
@@ -264,9 +196,7 @@ def analysis_entry_points():
 
     return (
         EntryPoint(name="kernels.fused_neighbor_sum",
-                   build=_build_neighbor_sum("pallas")),
-        EntryPoint(name="kernels.fused_neighbor_sum.xla",
-                   build=_build_neighbor_sum("xla")),
+                   build=build_neighbor_sum),
         EntryPoint(name="kernels.fused_broadcast_select",
                    build=build_broadcast_select),
     )

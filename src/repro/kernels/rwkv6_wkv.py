@@ -22,17 +22,15 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
                 seq: int):
     n = r_ref.shape[3]
     u = u_ref[...][0]                              # (n,)
-    # scalar-array index: literal ints break pallas interpret on jax 0.4.37
-    zero = jnp.int32(0)
 
     def body(t, state):
-        rt = pl.load(r_ref, (zero, t, zero, slice(None)))    # (n,)
-        kt = pl.load(k_ref, (zero, t, zero, slice(None)))
-        vt = pl.load(v_ref, (zero, t, zero, slice(None)))
-        wt = pl.load(w_ref, (zero, t, zero, slice(None)))
+        rt = r_ref[0, t, 0]                         # (n,)
+        kt = k_ref[0, t, 0]
+        vt = v_ref[0, t, 0]
+        wt = w_ref[0, t, 0]
         kv = kt[:, None] * vt[None, :]                 # (n, n)
         out = rt @ (u[:, None] * kv + state)           # (n,)
-        pl.store(o_ref, (zero, t, zero, slice(None)), out)
+        o_ref[0, t, 0] = out
         return wt[:, None] * state + kv
 
     s_fin = jax.lax.fori_loop(0, seq, body, jnp.zeros((n, n), jnp.float32))

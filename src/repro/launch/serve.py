@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import frontends, transformer
 from repro.serve import ServeEngine
 
@@ -25,6 +26,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     key = jax.random.PRNGKey(args.seed)

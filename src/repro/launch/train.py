@@ -20,6 +20,7 @@ import pathlib
 from repro.configs import get_config
 from repro.core.netes import NetESConfig
 from repro.core.topology import TopologySpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.loop import TrainConfig, train_lm_netes, train_rl_netes
 
 
@@ -102,6 +103,7 @@ def main() -> None:
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     netes_cfg = NetESConfig(alpha=args.alpha, sigma=args.sigma,
                             p_broadcast=args.p_broadcast)

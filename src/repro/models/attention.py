@@ -145,39 +145,6 @@ def blockwise_attention(spec: AttnSpec, q: jax.Array, k: jax.Array,
     return out[:, :sq]
 
 
-def _register_barrier_rules():
-    """jax 0.4.x ships ``optimization_barrier`` without JVP/transpose/
-    batching rules, so any grad (ES-vs-gradient alignment test) or vmap
-    (the replica step's per-agent forward) through ``attention_block``
-    raises NotImplementedError. The barrier is semantically the identity —
-    it only pins XLA scheduling — so the rules below are the ones later
-    jax versions ship upstream: apply the barrier elementwise to tangents/
-    cotangents/batched operands."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p as prim
-        from jax.interpreters import ad, batching
-    except ImportError:       # newer jax: rules exist upstream
-        return
-    if prim not in ad.primitive_jvps:
-        def _jvp(primals, tangents, **params):
-            tangents = [ad.instantiate_zeros(t) for t in tangents]
-            return (prim.bind(*primals, **params),
-                    prim.bind(*tangents, **params))
-        ad.primitive_jvps[prim] = _jvp
-    if prim not in ad.primitive_transposes:
-        def _transpose(cts, *primals, **params):
-            cts = [ad.instantiate_zeros(ct) for ct in cts]
-            return prim.bind(*cts, **params)
-        ad.primitive_transposes[prim] = _transpose
-    if prim not in batching.primitive_batchers:
-        def _batcher(batched_args, batch_dims, **params):
-            return prim.bind(*batched_args, **params), batch_dims
-        batching.primitive_batchers[prim] = _batcher
-
-
-_register_barrier_rules()
-
-
 def attention_block(params, spec: AttnSpec, x: jax.Array,
                     positions: jax.Array, kv_x: Optional[jax.Array] = None,
                     kv_positions: Optional[jax.Array] = None,

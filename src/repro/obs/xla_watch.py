@@ -6,10 +6,10 @@ suite share one implementation (``benchmarks.common`` re-exports
 
 Two kinds of hook:
 
-* backend compiles — jax 0.4.x exposes a monitoring event
-  (``/jax/core/compile/backend_compile_duration``) fired once per XLA
-  backend compilation; listeners are cheap and composable.
-* host transfers — jax 0.4.37 has NO monitoring event for d2h copies,
+* backend compiles — ``jax.monitoring`` fires a duration event
+  (``/jax/core/compile/backend_compile_duration``) once per XLA backend
+  compilation; listeners are cheap and composable.
+* host transfers — JAX has NO monitoring event for d2h copies,
   so the counter wraps ``jax.device_get``, the repo's sanctioned drain
   path (DESIGN.md §7: metrics leave the device through chunked
   ``device_get`` calls, never through per-point ``float()`` coercion).
@@ -71,19 +71,17 @@ def count_backend_compiles() -> Iterator[List[str]]:
     """Yields a list that grows by one per XLA backend compilation —
     the fleet bench's steady-state gate (a warmed run must replay with
     ZERO compiles; scheduled topologies must match static runs)."""
-    from jax._src import monitoring
-
     counts: List[str] = []
 
     def cb(event, *a, **kw):
         if event == _COMPILE_EVENT:
             counts.append(event)
 
-    monitoring.register_event_duration_secs_listener(cb)
+    jax.monitoring.register_event_duration_secs_listener(cb)
     try:
         yield counts
     finally:
-        monitoring._unregister_event_duration_listener_by_callback(cb)
+        jax.monitoring.unregister_event_duration_listener(cb)
 
 
 @contextlib.contextmanager
@@ -131,8 +129,8 @@ class Watch:
     def start(self) -> "Watch":
         if self._active:
             return self
-        from jax._src import monitoring
-        monitoring.register_event_duration_secs_listener(self._on_compile)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_compile)
         _add_transfer_callback(self._on_transfer)
         self._active = True
         return self
@@ -143,8 +141,6 @@ class Watch:
     def stop(self) -> None:
         if not self._active:
             return
-        from jax._src import monitoring
-        monitoring._unregister_event_duration_listener_by_callback(
-            self._on_compile)
+        jax.monitoring.unregister_event_duration_listener(self._on_compile)
         _remove_transfer_callback(self._on_transfer)
         self._active = False

@@ -10,7 +10,6 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis.contracts import (
@@ -123,8 +122,8 @@ def test_branch_collective_parity_detects_divergent_switch():
                 lambda u: u * 2.0,
             ], v)
 
-        return shard_map(local, mesh=mesh, in_specs=(P(), P("agents")),
-                         out_specs=P("agents"), check_rep=False)(idx, x)
+        return jax.shard_map(local, mesh=mesh, in_specs=(P(), P("agents")),
+                             out_specs=P("agents"), check_vma=False)(idx, x)
 
     msgs = check_branch_collective_parity(
         _jaxpr(diverge, jnp.zeros((), jnp.int32), jnp.ones(4)))
@@ -137,8 +136,8 @@ def test_branch_collective_parity_detects_divergent_switch():
                 lambda u: jax.lax.ppermute(u * 2.0, "agents", perm),
             ], v)
 
-        return shard_map(local, mesh=mesh, in_specs=(P(), P("agents")),
-                         out_specs=P("agents"), check_rep=False)(idx, x)
+        return jax.shard_map(local, mesh=mesh, in_specs=(P(), P("agents")),
+                             out_specs=P("agents"), check_vma=False)(idx, x)
 
     assert check_branch_collective_parity(
         _jaxpr(parity, jnp.zeros((), jnp.int32), jnp.ones(4))) == []

@@ -134,6 +134,20 @@ def test_train_loop_shards_smoke():
     assert len(h["reward_mean"]) == 4
 
 
+def test_env_reward_rowwise_keys_follow_the_agent():
+    """``rowwise`` on a slice of the population with that slice's keys
+    gives the slice of the whole population's rewards — what lets a
+    shard evaluate its agents without changing their episodes."""
+    from repro.envs import resolve_task
+    reward_fn, dim, init_fn, _, _ = resolve_task("pendulum")
+    params = jax.vmap(init_fn)(jax.random.split(jax.random.PRNGKey(0), 6))
+    key = jax.random.PRNGKey(3)
+    whole = np.asarray(reward_fn(params, key))
+    keys = jax.random.split(key, 8)[:6]   # split(k, n_pad)[:N] prefix
+    part = np.asarray(reward_fn.rowwise(params[2:5], keys[2:5]))
+    np.testing.assert_array_equal(part, whole[2:5])
+
+
 def test_checkpoint_roundtrip_solo(tmp_path):
     from repro.checkpoint import io
     state0 = netes.init_state(jax.random.PRNGKey(3), N, D)
@@ -219,6 +233,25 @@ for name, (topo, chan) in legs.items():
             assert np.array_equal(np.asarray(msgs),
                                   np.asarray(ref_msgs)), \
                 (name, ndev, "msgs")
+
+# an env reward draws each agent's episode from that agent's key, which
+# must follow the agent to whichever shard holds it
+from repro.envs import resolve_task
+env_reward, env_dim, env_init, _, _ = resolve_task("pendulum")
+env_state0 = netes.init_state(jax.random.PRNGKey(1), 24, env_dim,
+                              init_fn=env_init)
+env_topo = topology_repr.from_dense(
+    topology.erdos_renyi(24, p=0.3, seed=1), "sparse")
+env_ref = None
+for ndev in (None, 8):
+    mesh = None if ndev is None else fleet_shard.build_mesh(ndev)
+    st = fleet_shard.ShardedNetES(env_topo, env_reward, cfg,
+                                  mesh=mesh).run(env_state0, 2)[0]
+    th = np.asarray(jax.device_get(st.thetas))
+    if env_ref is None:
+        env_ref = th
+    else:
+        assert np.array_equal(th, env_ref), ("env reward", ndev)
 
 # scheduled topology (replicated mode): mesh sizes agree with solo
 sched = topology_sched.compile_schedule(

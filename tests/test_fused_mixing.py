@@ -2,8 +2,8 @@
 
 Property tests (via the hypothesis shim) for the wire codec
 (``core.wire_format`` ≡ the channel's fake-quant ``_quantize``, bit for
-bit), the fused kernel against its jnp oracle on BOTH lowerings (XLA
-and Pallas-interpret), the ``weighted_neighbor_sum`` WirePayload
+bit), the fused kernels against their jnp oracles (the broadcast
+select on both lowerings), the ``weighted_neighbor_sum`` WirePayload
 dispatch across representations × channels, the fused broadcast-best
 select, end-to-end fused-vs-unfused trajectory parity (static,
 scheduled, distributed), channel-aware representation selection, and
@@ -101,7 +101,7 @@ def test_slice_stack_indexes_message_axis():
 
 
 # ---------------------------------------------------------------------------
-# fused kernel vs oracle, both lowerings
+# fused kernels vs oracles
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=15, deadline=None)
@@ -120,31 +120,8 @@ def test_fused_neighbor_sum_matches_oracle(n, bits, seed, masked):
     want = ref.fused_neighbor_sum_ref(topo.neighbor_idx,
                                       topo.neighbor_mask, coeff,
                                       wp.codes, wp.scale, em)
-    for backend, interp in (("xla", None), ("pallas", True)):
-        got = nfm.fused_neighbor_sum(topo.neighbor_idx,
-                                     topo.neighbor_mask, coeff,
-                                     wp.codes, wp.scale, em,
-                                     backend=backend, interpret=interp)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5,
-                                   err_msg=backend)
-
-
-def test_fused_neighbor_sum_pallas_pads_odd_dim():
-    """D not a multiple of the tile: the pallas lowering pads and
-    crops; both lowerings agree with the oracle."""
-    n, d = 16, 700                  # 700 > TILE_D=512 and not divisible
-    rng = np.random.default_rng(3)
-    topo = _topo("sparse", n=n, p=0.3)
-    coeff = jnp.asarray(rng.normal(size=n), jnp.float32)
-    wp = wire_format.encode(
-        jnp.asarray(rng.normal(size=(n, d)), jnp.float32), 8, True)
-    want = ref.fused_neighbor_sum_ref(topo.neighbor_idx,
-                                      topo.neighbor_mask, coeff,
-                                      wp.codes, wp.scale)
     got = nfm.fused_neighbor_sum(topo.neighbor_idx, topo.neighbor_mask,
-                                 coeff, wp.codes, wp.scale,
-                                 backend="pallas", interpret=True)
+                                 coeff, wp.codes, wp.scale, em)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -159,21 +136,39 @@ def test_fused_broadcast_select_matches_oracle(bits, seed, flag):
                             bits, False)
     do = jnp.asarray(flag)
     want = ref.broadcast_select_ref(wp.codes, wp.scale, do, th)
-    for backend, interp in (("xla", None), ("pallas", True)):
+    for backend in ("auto", "pallas"):
         got = nfm.fused_broadcast_select(wp.codes, wp.scale, do, th,
-                                         backend=backend,
-                                         interpret=interp)
+                                         backend=backend)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
                                       err_msg=backend)
 
 
-def test_backend_resolution_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_FUSED_BACKEND", "pallas")
+@pytest.mark.parametrize("flag", [False, True])
+def test_fused_broadcast_select_ragged_tiles(flag):
+    """A 2-D grid whose edge blocks overhang both axes (N=600, D=700
+    over 512×512 tiles) is still exactly the oracle: Pallas masks the
+    overhang, nothing is padded."""
+    rng = np.random.default_rng(7)
+    th = jnp.asarray(rng.normal(size=(600, 700)), jnp.float32)
+    wp = wire_format.encode(jnp.asarray(rng.normal(size=700), jnp.float32),
+                            8, False)
+    do = jnp.asarray(flag)
+    got = nfm.fused_broadcast_select(wp.codes, wp.scale, do, th,
+                                     backend="pallas")
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(ref.broadcast_select_ref(wp.codes, wp.scale, do, th)))
+
+
+def test_backend_resolution_by_platform(monkeypatch):
+    """The platform alone picks the lowering: compiled Pallas on TPU,
+    XLA elsewhere; explicit Pallas off TPU interprets."""
+    monkeypatch.setattr(nfm, "_on_tpu", lambda: True)
     assert nfm._resolve_backend("auto") == "pallas"
-    monkeypatch.setenv("REPRO_FUSED_BACKEND", "xla")
+    assert nfm._resolve_interpret(None) is False
+    monkeypatch.setattr(nfm, "_on_tpu", lambda: False)
     assert nfm._resolve_backend("auto") == "xla"
-    monkeypatch.delenv("REPRO_FUSED_BACKEND")
-    assert nfm._resolve_backend("auto") in nfm.BACKENDS
+    assert nfm._resolve_interpret(None) is True
     with pytest.raises(ValueError):
         nfm._resolve_backend("cuda")
 

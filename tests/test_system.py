@@ -207,3 +207,35 @@ def test_synthetic_data_is_learnable_structure():
                 reps += 1
             big.add((a, bb))
     assert reps > 10
+
+
+def _run_chip_smoke(cwd):
+    """Run ``chip_smoke.py`` in ``cwd`` on the CPU, with no PYTHONPATH."""
+    import os
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    return subprocess.run([sys.executable, "chip_smoke.py"], cwd=cwd,
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """No accelerator: non-zero exit and no result line."""
+    from pathlib import Path
+    res = _run_chip_smoke(Path(__file__).resolve().parent.parent)
+    assert res.returncode != 0
+    assert '"ok"' not in res.stdout
+    assert "needs a TPU" in res.stderr
+
+
+def test_chip_smoke_fails_without_the_repo(tmp_path):
+    """The script alone, in a directory without the program, fails."""
+    import shutil
+    from pathlib import Path
+    root = Path(__file__).resolve().parent.parent
+    shutil.copy(root / "chip_smoke.py", tmp_path)
+    res = _run_chip_smoke(tmp_path)
+    assert res.returncode != 0
+    assert '"ok"' not in res.stdout

@@ -144,8 +144,12 @@ def test_prior_matches_numpy_approximations(n, p):
     from repro.core import theory
     rho = float(theory.reachability_prior(n, p))
     gam = float(theory.homogeneity_prior(n, p))
-    assert rho == pytest.approx(topology.reachability_approx(n, p),
-                                rel=1e-4)
+    kmin = p * (n - 1) - 2.0 * np.sqrt(p * (n - 1) * (1 - p))
+    # the jnp prior floors k_min at 1 (the self-loop); the numpy closed
+    # form is the paper's, unfloored (e.g. n=50, p=0.109 gives k_min<1)
+    want_rho = (topology.reachability_approx(n, p) if kmin >= 1.0
+                else float(np.sqrt(p * p * n ** 3)))
+    assert rho == pytest.approx(want_rho, rel=1e-4)
     assert gam == pytest.approx(topology.homogeneity_approx(n, p),
                                 rel=1e-4, abs=1e-5)
     # prior_score uses the paper's large-n simplification ρ̂ = 1/(p√n)
