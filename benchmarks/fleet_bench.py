@@ -31,10 +31,9 @@ show extra compiles here.
 
 Quantized-channel entries (``chan_q8/q4/q1`` and their ``_unfused``
 controls, DESIGN.md §12) run the same sparse 1024-agent loop under a
-wire-quantizing channel twice — through the fused mixing∘codec∘mask
-kernel and through the decode-then-contract control — and gate that the
-fused path matches the control's trajectory exactly while landing at or
-below its step time.
+wire-quantizing channel twice — through the wire path and through the
+fake-quant control — and gate that the wire path matches the control's
+trajectory exactly while landing at or below its step time.
 
 Every gated step time is the MEDIAN over ``TIMED_REPLAYS`` warmed
 replays, with per-replay min/max recorded in the artifact, so a single
@@ -253,8 +252,7 @@ def fleet_scheduled(quick: bool = False, static_compiles: int = 0):
     return entries
 
 
-# The wire-quantized channels the fused mixing kernel serves
-# (DESIGN.md §12): (entry suffix, bits).
+# The wire-quantized channels (DESIGN.md §12): (entry suffix, bits).
 CHANNEL_BITS = [("q8", 8), ("q4", 4), ("q1", 1)]
 
 # One-sided fused-vs-unfused step-time gate slack: the fused path must
@@ -269,19 +267,20 @@ FUSED_SLACK = 1.2
 def fleet_channels(quick: bool = False):
     """Quantized-channel legs at N=1024 (the tentpole's measured gate):
     the sparse ER fleet run under q8/q4/q1 wire channels, once through
-    the FUSED mixing∘codec∘mask kernel (``channel_fused=True``, the
-    default — ``weighted_neighbor_sum`` receives the WirePayload and
-    dispatches ``kernels/netes_fused_mixing``) and once through the
-    unfused decode-then-contract control (``channel_fused=False``).
+    the wire path (``channel_fused=True``, the default —
+    ``weighted_neighbor_sum`` receives the WirePayload and decodes it
+    once; the broadcast-best payload goes through
+    ``kernels/netes_fused_mixing.fused_broadcast_select``) and once
+    through the fake-quant control (``channel_fused=False``).
 
     Gates, per bit-width:
 
     * fused and unfused runs follow the SAME training trajectory (the
-      fused kernel is exact w.r.t. the codec, not approximately so);
+      wire path is exact w.r.t. the codec, not approximately so);
     * both replay compile-free (the WirePayload pytree lives in the
       scan like any other carry — no per-step retrace);
-    * fused median step time ≤ unfused × ``FUSED_SLACK`` — the "one
-      memory pass" claim, measured end-to-end at fleet scale.
+    * fused median step time ≤ unfused × ``FUSED_SLACK`` — the wire
+      path costs no more than the control, end-to-end at fleet scale.
 
     Baselines additionally hold each leg's wire bytes (exact — fusion
     never changes what moves on the wire) and step time (±30%).
@@ -342,17 +341,17 @@ def fleet_channels(quick: bool = False):
                        "model_step_us": perfmodel.modeled_step_us(
                            N_FLEET, fan_in, "sparse",
                            elem_bytes=channel.elem_bytes,
-                           codec_stages=1, fused=fused)}))
+                           codec_stages=1)}))
     for suffix, _bits in CHANNEL_BITS:
         f_eval, u_eval = finals[(suffix, True)], finals[(suffix, False)]
         assert abs(f_eval - u_eval) <= 1e-3 * max(1.0, abs(u_eval)), (
             f"chan_{suffix}: fused trajectory diverged from unfused "
-            f"({f_eval} vs {u_eval}) — the kernel is not codec-exact")
+            f"({f_eval} vs {u_eval}) — the wire path is not codec-exact")
         f_t, u_t = meds[(suffix, True)], meds[(suffix, False)]
         assert f_t <= u_t * FUSED_SLACK, (
             f"chan_{suffix}: fused median step {f_t * 1e3:.1f}ms above "
             f"unfused control {u_t * 1e3:.1f}ms × {FUSED_SLACK} — the "
-            "fused path lost its one-memory-pass advantage")
+            "wire path costs more than the fake-quant control")
     return entries
 
 

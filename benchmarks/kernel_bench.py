@@ -10,11 +10,6 @@ mixing backends on this host AND models the distributed step on the
 production target, where the all-gather's N·D bytes — not flops — bind
 (Chen et al. 2018). The winner column drives
 ``topology_repr.select_representation``'s cutoffs.
-
-``fused_crossover`` is the same table for the fused wire path
-(DESIGN.md §12): fused mixing∘codec∘mask kernel vs the unfused
-decode-then-contract control on an int8-quantized payload, measured on
-this host and modeled at production scale.
 """
 from __future__ import annotations
 
@@ -121,84 +116,6 @@ def sparse_crossover(quick: bool = False):
     return table
 
 
-def fused_crossover(quick: bool = False):
-    """Fused-vs-unfused quantized sparse mixing over (N, p) (DESIGN.md
-    §12): per cell, measured host ms for the fused wire kernel (XLA
-    lowering — the CPU production path ``weighted_neighbor_sum``
-    dispatches) versus the unfused decode-then-contract control on the
-    same int8 wire payload, plus the modeled production step per path.
-    The model's fused column is strictly ≤ its unfused one at every
-    (N, K) — fusion deletes the decode pass and touches nothing else —
-    so the table's job is the measured counterpart: where the f32
-    (N, K, D) gather intermediate starts to cost on a real host.
-    """
-    from repro.core import topology, topology_repr, wire_format
-    from repro.kernels import netes_fused_mixing as nfm
-
-    rng = np.random.default_rng(0)
-    d = 64 if quick else 256
-    iters = 3 if quick else 5
-    bits = 8
-    elem = bits / 8.0
-
-    @jax.jit
-    def unfused(idx, mask, coeff, codes, scale):
-        # the decode-then-contract control: dequantize the full payload,
-        # then gather f32 rows and contract — the (N, K, D) intermediate
-        # the fused kernel exists to delete
-        values = wire_format.decode(codes, scale)
-        w = mask * jnp.take(coeff, idx)
-        return jnp.einsum("jk,jkd->jd", w, jnp.take(values, idx, axis=0))
-
-    table = []
-    for n in (256, 1024):
-        for p in (0.05, 0.1):
-            adj = topology.erdos_renyi(n, p=p, seed=0)
-            topo = topology_repr.from_dense(adj, "sparse")
-            coeff = jnp.asarray(rng.normal(size=n), jnp.float32)
-            x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
-            wp = wire_format.encode(x, bits, batched=True)
-
-            dt_fused = _time(nfm.fused_neighbor_sum, topo.neighbor_idx,
-                             topo.neighbor_mask, coeff, wp.codes,
-                             wp.scale, iters=iters)
-            dt_unfused = _time(unfused, topo.neighbor_idx,
-                               topo.neighbor_mask, coeff, wp.codes,
-                               wp.scale, iters=iters)
-            err = float(jnp.abs(
-                nfm.fused_neighbor_sum(topo.neighbor_idx,
-                                       topo.neighbor_mask, coeff,
-                                       wp.codes, wp.scale)
-                - ref.fused_neighbor_sum_ref(topo.neighbor_idx,
-                                             topo.neighbor_mask, coeff,
-                                             wp.codes, wp.scale)).max())
-            assert err < 1e-4, err
-
-            k_max = topo.k_max
-            m_fused = perfmodel.modeled_step_us(
-                n, k_max, "sparse", elem_bytes=elem, codec_stages=1,
-                fused=True)
-            m_unfused = perfmodel.modeled_step_us(
-                n, k_max, "sparse", elem_bytes=elem, codec_stages=1,
-                fused=False)
-            assert m_fused <= m_unfused, (m_fused, m_unfused)
-            winner = "fused" if dt_fused <= dt_unfused else "unfused"
-            table.append((n, p, k_max, dt_fused, dt_unfused, m_fused,
-                          m_unfused, winner))
-            common.emit(
-                f"kernel.fused_crossover.n{n}_p{p}", dt_fused,
-                f"K={k_max} unfused_ms={dt_unfused * 1e3:.2f} "
-                f"model_fused_us={m_fused:.0f} "
-                f"model_unfused_us={m_unfused:.0f} winner={winner}")
-    print("# N     p     K_max  fused_ms  unfused_ms  model_fused_us  "
-          "model_unfused_us  winner")
-    for row in table:
-        print(f"# {row[0]:<5} {row[1]:<5} {row[2]:<6} {row[3]*1e3:<9.2f} "
-              f"{row[4]*1e3:<11.2f} {row[5]:<15.0f} {row[6]:<17.0f} "
-              f"{row[7]}")
-    return table
-
-
 def run(quick: bool = False):
     entries = []
     rng = np.random.default_rng(0)
@@ -271,24 +188,10 @@ def run(quick: bool = False):
     entries.append(registry.Entry(
         name="kernel.pallas_sparse_interpret_check", eval_score=float(ok)))
 
-    # fused wire kernels (DESIGN.md §12) vs the jnp oracles — the
-    # mixing∘codec∘mask contraction (XLA on every platform) and the
-    # broadcast-best select's Pallas lowering (compiled on TPU,
-    # interpreted elsewhere), both reading int8 wire codes
+    # fused broadcast-best select (DESIGN.md §12) vs its jnp oracle: the
+    # Pallas lowering, compiled on TPU and interpreted elsewhere
     from repro.core import wire_format
     from repro.kernels import netes_fused_mixing as nfm
-    wp8 = wire_format.encode(th[:8, :256], 8, batched=True)
-    out_fk = nfm.fused_neighbor_sum(
-        jnp.asarray(idx8), jnp.asarray(mask8), wt[:8], wp8.codes,
-        wp8.scale)
-    out_fr = ref.fused_neighbor_sum_ref(
-        jnp.asarray(idx8), jnp.asarray(mask8), wt[:8], wp8.codes,
-        wp8.scale)
-    ok = bool(jnp.allclose(out_fk, out_fr, rtol=1e-4, atol=1e-4))
-    common.emit("kernel.fused_neighbor_sum_check", 0.0, f"allclose={ok}")
-    entries.append(registry.Entry(
-        name="kernel.fused_neighbor_sum_check", eval_score=float(ok)))
-
     bw = wire_format.encode(th[0, :256], 8, batched=False)
     out_bk = nfm.fused_broadcast_select(
         bw.codes, bw.scale, jnp.asarray(True), th[:8, :256],
@@ -312,21 +215,6 @@ def run(quick: bool = False):
             extra={"k_max": k_max, "sparse_ms": dt_sparse * 1e3,
                    "circulant_ms": dt_circ * 1e3,
                    "model_dense_us": m_dense, "model_sparse_us": m_sparse,
-                   "winner": winner}))
-    for (n_, p_, k_max, dt_fused, dt_unfused, m_fused, m_unfused,
-         winner) in fused_crossover(quick=quick):
-        entries.append(registry.Entry(
-            name=f"kernel.fused_crossover.n{n_}_p{p_}",
-            wall_s=dt_fused,
-            # gated metric: modeled per-chip bytes of the q8 wire —
-            # exact, machine-independent, identical for both paths
-            wire_bytes=perfmodel.wire_bytes(n_, k_max, "sparse",
-                                            elem_bytes=1.0),
-            extra={"k_max": k_max, "bits": 8,
-                   "unfused_ms": dt_unfused * 1e3,
-                   "fused_ms": dt_fused * 1e3,
-                   "model_fused_us": m_fused,
-                   "model_unfused_us": m_unfused,
                    "winner": winner}))
     return entries
 

@@ -26,9 +26,9 @@ realized traffic stays below ``2·p``× of FC's — degrading no faster on
 byte" cashes out to at CI scale (the paper's N=1000 regime strengthens
 it; see ROADMAP).
 
-The quantized sparse-ER cells run through the FUSED wire kernel
-(DESIGN.md §12); ``*_unfused`` control legs re-run them through the
-decode-then-contract path and gate exact byte and trajectory agreement.
+The quantized sparse-ER cells run through the wire path (DESIGN.md
+§12); ``*_unfused`` control legs re-run them through the fake-quant path
+and gate exact byte and trajectory agreement.
 """
 from __future__ import annotations
 
@@ -137,13 +137,13 @@ def run(quick: bool = False):
                        "timed_compiles": len(compiles)}))
 
     # ---- fused-vs-unfused controls (DESIGN.md §12) --------------------
-    # The sparse ER quantized cells above ran through the fused
-    # mixing∘codec∘mask wire kernel (``TrainConfig.channel_fused``
-    # defaults True and ``Channel.wire_fused`` holds for a single
-    # quantize stage on a sparse graph). These control legs re-run them
-    # through the decode-then-contract path and gate EXACT agreement:
-    # fusion must change neither the realized wire traffic (exact-gated
-    # bytes) nor the training trajectory — only the step time.
+    # The sparse ER quantized cells above ran through the wire path
+    # (``TrainConfig.channel_fused`` defaults True and
+    # ``Channel.wire_fused`` holds for a single quantize stage on a
+    # sparse graph). These control legs re-run them through the
+    # fake-quant path and gate EXACT agreement: the path must change
+    # neither the realized wire traffic (exact-gated bytes) nor the
+    # training trajectory — only the step time.
     dim = resolve_task(TASK)[1]
     for suffix in ("q8", "q4", "q1"):
         chan = dict(CHANNELS)[suffix]
@@ -166,14 +166,14 @@ def run(quick: bool = False):
         realized = int(round(msgs * channel.payload_bytes(dim)))
         mean_eval = float(np.mean(scores))
         assert realized == bytes_[("erdos_renyi", suffix)], (
-            f"{suffix}: fused wire bytes "
+            f"{suffix}: wire-path bytes "
             f"{bytes_[('erdos_renyi', suffix)]} != unfused {realized} "
             "— fusion changed what moved on the wire")
         fused_eval = evals[("erdos_renyi", suffix)]
         assert abs(mean_eval - fused_eval) <= \
             1e-3 * max(1.0, abs(mean_eval)), (
             f"{suffix}: fused trajectory diverged from unfused "
-            f"({fused_eval} vs {mean_eval}) — the kernel is not "
+            f"({fused_eval} vs {mean_eval}) — the wire path is not "
             "codec-exact")
         step_s = wall / (iters * len(seeds))
         common.emit(f"resilience.erdos_renyi.{suffix}_unfused", step_s,

@@ -8,8 +8,8 @@ has shipped-and-fixed one regression at a time:
   host syncs / Python branches inside traced code, and PRNG key reuse.
 * **Layer 2 (jaxpr)** — ``contracts`` + ``registry``: abstract traces
   of the registered entry points (core run/scheduled, the replica and
-  consensus steps, the sharded fleet comm plans, both fused wire
-  kernels) checked for host callbacks, weak scan carries,
+  consensus steps, the sharded fleet comm plans, the fused broadcast
+  select) checked for host callbacks, weak scan carries,
   branch-divergent collectives, and unpinned FMA seams (the PR 7
   bit-parity contract).
 

@@ -255,10 +255,10 @@ class Channel:
 
     def wire_fused(self, topo: Topology) -> bool:
         """Trace-time dispatch decision for a channel-carrying step:
-        route through ``apply_wire`` + the fused contraction? Sparse
-        only — that is where the (N, K, D) gather the fusion deletes
-        lives; dense/circulant graphs keep the fake-quant path (the
-        encoded payload would be decoded whole-array right back)."""
+        route through ``apply_wire`` (the payload stays int8 until the
+        contraction decodes it, and the broadcast-best payload goes
+        through the fused broadcast select)? Sparse only; dense and
+        circulant graphs keep the fake-quant path."""
         return self.fused and self.wire_quantized and topo.kind == "sparse"
 
     @property
@@ -370,8 +370,8 @@ class Channel:
         stage order, trigger decisions, dropout draws, and traffic
         accounting, but the quantize stage ENCODES (``wire_format.encode``)
         instead of fake-quantizing, so the returned payload is a pytree of
-        ``WirePayload`` leaves the fused contraction reads directly — the
-        decoded f32 payload never materializes. Requires
+        ``WirePayload`` leaves, decoded where they are contracted
+        (``topology_repr.weighted_neighbor_sum``). Requires
         ``wire_quantized`` (checked at trace time): every stage that
         reads payload VALUES runs before the encode, and only mask-only
         stages (dropout) follow it."""

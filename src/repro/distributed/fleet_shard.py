@@ -520,11 +520,12 @@ class ShardedNetES:
                                           shaped.astype(buf.dtype), buf)
             return mixed, wsum, operands["deg"], cs, chan_metrics
         # full: rank-1 — Σ_i R̃_i·wire_i is one replicated (D,) vector.
-        # A gemv's accumulation order is the backend's choice and varies
-        # with the program around it; rounded products summed by one
-        # reduction give the same bits on every mesh size.
-        svec = jax.lax.optimization_barrier(
-            shaped.astype(buf.dtype)[:, None] * buf).sum(axis=0)
+        # Spelled as multiply + reduce, not a gemv: XLA:CPU lowers a gemv
+        # with a different loop order when the [:n] slice fuses into it
+        # (mesh sizes with n_pad > n), while the reduce keeps one order.
+        # Both forms compile to one fused pass with the same temporaries
+        # for v5e — no (N, D) product is materialized.
+        svec = (shaped.astype(buf.dtype)[:, None] * buf).sum(axis=0)
         wsum_scalar = shaped.sum()
         mixed = jnp.broadcast_to(svec, th.shape)
         wsum = jnp.broadcast_to(wsum_scalar, (n_loc,))

@@ -1,32 +1,19 @@
-"""Fused mixing∘codec∘mask over quantized wire payloads (DESIGN.md §12).
+"""Fused broadcast-best select over a quantized wire payload (DESIGN.md §12).
 
-The unfused channel hot path runs three passes — decode the quantized
-payload to f32, apply the live-link mask, contract the weighted neighbor
-sum — each materializing an (N, K, D)-scale intermediate. The fused
-neighbor sum takes the int8 wire codes (``core.wire_format.WirePayload``)
-and does all three over the block-sparse (N, K_max) neighbor layout, so
-the (N, K, D) gather never exists:
+``fused_broadcast_select`` is ``where(do_broadcast, decode(codes, scale),
+θ)`` in one pass: every agent adopts the int8 broadcast-best payload
+without a decoded (D,) + broadcast (N, D) intermediate round trip. It has
+two lowerings. ``backend="auto"`` picks by platform: the Pallas kernel,
+compiled, on TPU; the XLA lowering elsewhere. An explicit
+``backend="pallas"`` off TPU runs the same kernel program in interpret
+mode — how the CPU tests check it against its oracle,
+``ref.broadcast_select_ref``.
 
-    out[j] = Σ_k (m_jk · em_jk · coeff[i_jk]) · (codes[i_jk] · scale[i_jk])
-
-with every product rounded in the order the unfused path rounds it, so
-the two agree bit for bit.
-
-``fused_neighbor_sum`` is XLA on every platform: a Pallas lowering with
-an in-kernel row gather is refused by the TPU compiler (``dynamic_slice``
-has no Mosaic lowering), so a compiled gather kernel needs its own
-design and a measured cell on each side before it comes back.
-
-``fused_broadcast_select`` (decode-where-flagged in one pass instead of
-decode → broadcast → select) has two lowerings. ``backend="auto"`` picks
-by platform: the Pallas kernel, compiled, on TPU; the XLA lowering
-elsewhere. An explicit ``backend="pallas"`` off TPU runs the same kernel
-program in interpret mode — how the CPU tests check it against its
-oracle.
-
-Oracles: ``ref.fused_neighbor_sum_ref`` / ``ref.broadcast_select_ref``
-(decode-then-contract, (N, K, D) materialized — the correctness
-contract the fusion is tested against).
+The neighbor contraction has no kernel here: ``topology_repr.
+weighted_neighbor_sum`` decodes a wire payload once and runs the f32
+path. A Pallas gather kernel with an in-kernel row gather is refused by
+the TPU compiler (``dynamic_slice`` has no Mosaic lowering), so one
+needs its own design and a chip-measured cell on each side.
 """
 from __future__ import annotations
 
@@ -63,51 +50,6 @@ def _resolve_backend(backend: str) -> str:
 def _resolve_interpret(interpret) -> bool:
     # Pallas compiles on TPU and interprets everywhere else.
     return not _on_tpu() if interpret is None else bool(interpret)
-
-
-# ---------------------------------------------------------------------------
-# fused neighbor sum
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("out_dtype",))
-def fused_neighbor_sum(neighbor_idx: jax.Array, neighbor_mask: jax.Array,
-                       coeff: jax.Array, codes: jax.Array,
-                       scale: jax.Array, edge_mask=None, *,
-                       out_dtype=jnp.float32) -> jax.Array:
-    """``out_j = Σ_k mask_jk · em_jk · coeff_{i_jk} · codes_{i_jk} ·
-    scale_{i_jk}`` — Eq. 3's neighbor contraction straight off the wire.
-
-    neighbor_idx (N, K_max) int32; neighbor_mask / edge_mask (N, K_max);
-    coeff (N,) f32; codes (N, D) int8; scale (N, 1) f32 (per-message
-    decode scale). Returns (N, D) in ``out_dtype``.
-
-    One decode of the wire codes up front (``wire_format.decode``, one
-    (N, D) f32 slab), then the same ×4-unrolled slot loop and the same
-    weight formation as the f32 sparse path in
-    ``topology_repr.weighted_neighbor_sum``. XLA:CPU has no fused
-    int8-gather·convert·fma, so a per-slot decode measured SLOWER than
-    one up-front pass.
-    """
-    mask = neighbor_mask if edge_mask is None else neighbor_mask * edge_mask
-    ws = mask * jnp.take(coeff.astype(jnp.float32), neighbor_idx)
-    values = wire_format.decode(codes, scale)
-    k_max = neighbor_idx.shape[1]
-
-    def one(c, acc):
-        col = neighbor_idx[:, c]
-        return acc + ws[:, c, None] * jnp.take(values, col, axis=0)
-
-    acc = jnp.zeros(codes.shape, jnp.float32)
-    k4 = k_max - k_max % 4
-    if k4:
-        def body(kk, a):
-            for u in range(4):
-                a = one(kk * 4 + u, a)
-            return a
-        acc = jax.lax.fori_loop(0, k4 // 4, body, acc)
-    for c in range(k4, k_max):
-        acc = one(c, acc)
-    return acc.astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -167,24 +109,9 @@ def fused_broadcast_select(codes: jax.Array, scale: jax.Array,
 # ---------------------------------------------------------------------------
 
 def analysis_entry_points():
-    """Contract-linter entry points for both fused wire kernels (the
-    broadcast select in its Pallas lowering, interpreted). Deliberately
-    NOT under the fma-seam contract: the XLA slot loop's unguarded
-    mul→add is shape-uniform by construction (the whole (N, D) slab
-    lives in one program), so FMA contraction cannot break cross-shard
-    parity here."""
+    """Contract-linter entry point for the broadcast select in its Pallas
+    lowering, interpreted."""
     from repro.analysis.registry import EntryPoint
-
-    def _wire_args(n=8, k=4, d=16):
-        return (jnp.zeros((n, k), jnp.int32),      # neighbor_idx
-                jnp.ones((n, k), jnp.float32),     # neighbor_mask
-                jnp.ones((n,), jnp.float32),       # coeff
-                jnp.zeros((n, d), jnp.int8),       # codes
-                jnp.ones((n, 1), jnp.float32))     # scale
-
-    def build_neighbor_sum():
-        fn = functools.partial(fused_neighbor_sum, out_dtype=jnp.float32)
-        return fn, _wire_args(), {}
 
     def build_broadcast_select():
         d, n = 16, 8
@@ -195,8 +122,6 @@ def analysis_entry_points():
         return fn, args, {}
 
     return (
-        EntryPoint(name="kernels.fused_neighbor_sum",
-                   build=build_neighbor_sum),
         EntryPoint(name="kernels.fused_broadcast_select",
                    build=build_broadcast_select),
     )

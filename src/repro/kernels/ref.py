@@ -40,11 +40,11 @@ def sparse_mixing_ref(neighbor_idx, neighbor_mask, w_theta, w_eps, theta,
     return mixed.astype(theta.dtype)
 
 
-def fused_neighbor_sum_ref(neighbor_idx, neighbor_mask, coeff, codes,
-                           scale, edge_mask=None, *, out_dtype=jnp.float32):
-    """Decode-then-contract oracle for ``netes_fused_mixing.
-    fused_neighbor_sum`` — deliberately materializes everything the
-    fusion deletes: the decoded f32 payload AND the (N, K, D) gather.
+def wire_neighbor_sum_ref(neighbor_idx, neighbor_mask, coeff, codes,
+                          scale, edge_mask=None, *, out_dtype=jnp.float32):
+    """Decode-then-contract oracle for ``topology_repr.
+    weighted_neighbor_sum`` on a sparse topology and a wire payload — one
+    (N, K, D) gather and einsum instead of the slot loop.
 
         out_j = Σ_k m_jk · em_jk · coeff_{i_jk} · (codes · scale)_{i_jk}
     """

@@ -124,12 +124,9 @@ class TrainConfig:
 
 
 def build_topology(tc: TrainConfig) -> topology_repr.Topology:
-    """TopologySpec → representation-selected Topology (DESIGN.md §3).
-    The run's channel biases ``auto`` selection: a fused-eligible
-    quantizing channel raises the sparse cutoff (DESIGN.md §12)."""
+    """TopologySpec → representation-selected Topology (DESIGN.md §3)."""
     return topology_repr.from_spec(tc.topology,
-                                   representation=tc.representation,
-                                   channel=build_channel(tc))
+                                   representation=tc.representation)
 
 
 def build_schedule(tc: TrainConfig) -> Optional[TopologySchedule]:

@@ -34,7 +34,6 @@ def test_registry_collects_every_hooked_module():
     assert {"netes.run", "netes.run_scheduled", "netes_dist.replica_step",
             "netes_dist.consensus_step", "fleet_shard.solo_step",
             "fleet_shard.slot_contract", "fleet_shard.dense_contract",
-            "kernels.fused_neighbor_sum",
             "kernels.fused_broadcast_select"} <= names
 
 

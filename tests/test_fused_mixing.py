@@ -1,20 +1,18 @@
-"""Fused mixing∘codec∘mask wire path (DESIGN.md §12).
+"""Quantized wire path (DESIGN.md §12).
 
 Property tests (via the hypothesis shim) for the wire codec
 (``core.wire_format`` ≡ the channel's fake-quant ``_quantize``, bit for
-bit), the fused kernels against their jnp oracles (the broadcast
-select on both lowerings), the ``weighted_neighbor_sum`` WirePayload
-dispatch across representations × channels, the fused broadcast-best
-select, end-to-end fused-vs-unfused trajectory parity (static,
-scheduled, distributed), channel-aware representation selection, and
-checkpoint resume through the fused path.
+bit), the wire neighbor sum and the fused broadcast select against their
+jnp oracles (the select on both lowerings), the ``weighted_neighbor_sum``
+WirePayload dispatch across representations, and end-to-end
+wire-vs-fake-quant trajectory parity (static, scheduled, distributed),
+and checkpoint resume through the wire path.
 
-The fused kernel is EXACT with respect to the unfused codec path — the
-decode scale is folded into the contraction weights, a value-preserving
-reassociation on every lowering here — so the end-to-end parity
-assertions are bit-for-bit, not tolerance-based. Tolerances appear only
-where an oracle computes in a genuinely different order (the (N, K, D)
-einsum reference).
+A wire payload is decoded once and contracted by the same code as the
+fake-quant messages, and ``decode(encode(x))`` equals the fake-quant bit
+for bit, so the end-to-end parity assertions are equality, not
+tolerance. Tolerances appear only where an oracle computes in a
+genuinely different order (the (N, K, D) einsum reference).
 """
 import dataclasses
 
@@ -101,13 +99,13 @@ def test_slice_stack_indexes_message_axis():
 
 
 # ---------------------------------------------------------------------------
-# fused kernels vs oracles
+# wire neighbor sum and fused broadcast select vs oracles
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=15, deadline=None)
 @given(n=st.sampled_from([8, 64, 257]), bits=st.sampled_from([8, 4, 1]),
        seed=st.integers(0, 50), masked=st.sampled_from([False, True]))
-def test_fused_neighbor_sum_matches_oracle(n, bits, seed, masked):
+def test_wire_neighbor_sum_matches_oracle(n, bits, seed, masked):
     rng = np.random.default_rng(seed)
     adj = np.asarray(topology.erdos_renyi(n, p=0.3, seed=seed))
     topo = topology_repr.from_dense(adj, "sparse")
@@ -117,11 +115,11 @@ def test_fused_neighbor_sum_matches_oracle(n, bits, seed, masked):
     em = None
     if masked:
         em = cc.dropout_mask(jax.random.PRNGKey(seed), topo, 0.4)
-    want = ref.fused_neighbor_sum_ref(topo.neighbor_idx,
-                                      topo.neighbor_mask, coeff,
-                                      wp.codes, wp.scale, em)
-    got = nfm.fused_neighbor_sum(topo.neighbor_idx, topo.neighbor_mask,
-                                 coeff, wp.codes, wp.scale, em)
+    want = ref.wire_neighbor_sum_ref(topo.neighbor_idx,
+                                     topo.neighbor_mask, coeff,
+                                     wp.codes, wp.scale, em)
+    got = topology_repr.weighted_neighbor_sum(topo, coeff, wp,
+                                              edge_mask=em)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -247,8 +245,8 @@ def test_apply_wire_decodes_to_apply(spec, seed):
 @pytest.mark.parametrize("bits", [8, 4, 1])
 def test_wire_dispatch_matches_decoded(rep, bits):
     """``weighted_neighbor_sum(topo, coeff, WirePayload)`` ≡ the same
-    contraction on the decoded payload, for every representation (sparse
-    runs the fused kernel; dense/circulant decode-and-recurse)."""
+    contraction on the decoded payload, bit for bit, for every
+    representation (the payload is decoded once, then recursed)."""
     rng = np.random.default_rng(bits)
     topo = _topo(rep)
     coeff = jnp.asarray(rng.normal(size=N), jnp.float32)
@@ -257,12 +255,12 @@ def test_wire_dispatch_matches_decoded(rep, bits):
     want = topology_repr.weighted_neighbor_sum(
         topo, coeff, wire_format.decode_payload(wp))
     got = topology_repr.weighted_neighbor_sum(topo, coeff, wp)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_wire_dispatch_respects_edge_mask():
-    topo = _topo("sparse")
+@pytest.mark.parametrize("rep", ["dense", "sparse", "circulant"])
+def test_wire_dispatch_respects_edge_mask(rep):
+    topo = _topo(rep)
     rng = np.random.default_rng(7)
     coeff = jnp.asarray(rng.normal(size=N), jnp.float32)
     x = jnp.asarray(rng.normal(size=(N, DIM)), jnp.float32)
@@ -272,35 +270,7 @@ def test_wire_dispatch_respects_edge_mask():
         topo, coeff, wire_format.decode_payload(wp), edge_mask=em)
     got = topology_repr.weighted_neighbor_sum(topo, coeff, wp,
                                               edge_mask=em)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_select_representation_channel_aware():
-    """A wire-quantized channel raises the sparse cutoff: a graph in the
-    (SPARSE_CUTOFF, FUSED_CUTOFF) density band flips from dense to
-    sparse when the fused wire path is available."""
-    n = 64
-    lo = topology_repr.SPARSE_DENSITY_CUTOFF
-    hi = topology_repr.FUSED_SPARSE_DENSITY_CUTOFF
-    assert lo < hi
-    p_mid = (lo + hi) / 2
-    adj = np.asarray(topology.erdos_renyi(n, p=p_mid, seed=0))
-    density = (adj.sum() - n) / (n * (n - 1))
-    assert lo < density < hi, density
-    assert topology_repr.select_representation(adj) == "dense"
-    q = cc.compile_channel("quantize(bits=8)", n)
-    assert topology_repr.select_representation(adj, channel=q) == "sparse"
-    # ineligible channels change nothing
-    drop = cc.compile_channel("dropout(p=0.1,seed=0)", n)
-    assert topology_repr.select_representation(adj, channel=drop) \
-        == "dense"
-    q_off = cc.compile_channel("quantize(bits=8)", n, fused=False)
-    assert topology_repr.select_representation(adj, channel=q_off) \
-        == "dense"
-    # from_dense threads the channel through to the same decision
-    assert topology_repr.from_dense(adj, "auto", channel=q).kind \
-        == "sparse"
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
@@ -343,7 +313,7 @@ def test_scheduled_scan_fused_matches_unfused():
 
 
 def test_resume_mid_fused_channel_reproduces_eval_trace(tmp_path):
-    """Checkpoint/resume through the fused wire path: the post-resume
+    """Checkpoint/resume through the wire path: the post-resume
     eval trace is bit-for-bit the uninterrupted run's (the channel
     state, schedule state, and wire dispatch all travel)."""
     tc = TrainConfig(

@@ -69,19 +69,21 @@ def test_fused_broadcast_select_compiles_for_v5e(one_chip, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_netes_run_compiles_for_v5e(one_chip, monkeypatch):
+def test_netes_run_compiles_for_v5e(one_chip, monkeypatch, request):
     """The jitted training scan at the paper's pendulum configuration
     (N=1000, ER p=0.1, sparse, quantize(bits=8)), with the kernel
     dispatch steered to the choice it makes on a TPU."""
     monkeypatch.setattr(nfm, "_on_tpu", lambda: True)
-    jax.clear_caches()      # no trace made with the CPU's choice is reused
+    # No trace made with the CPU's kernel choice is reused here, and none
+    # made with the TPU's outlives this test on the worker.
+    jax.clear_caches()
+    request.addfinalizer(jax.clear_caches)
     n = 1000
     reward_fn, dim, init_fn, _, _ = resolve_task("pendulum")
     assert dim == PENDULUM_DIM
     spec = TopologySpec(family="erdos_renyi", n_agents=n, p=0.1, seed=0)
     chan = comm_channel.compile_channel("quantize(bits=8)", n)
-    topo = topology_repr.from_spec(spec, representation="auto",
-                                   channel=chan)
+    topo = topology_repr.from_spec(spec, representation="auto")
     assert topo.kind == "sparse"
     state = netes.init_state(jax.random.PRNGKey(0), n, dim,
                              init_fn=init_fn)
