@@ -67,26 +67,21 @@ PHASES = (
 
 
 class CompileClock:
-    """Sums JAX's compile-path durations and counts persistent-cache
-    hits and misses, through the public ``jax.monitoring`` listeners."""
+    """Sums JAX's compile-path durations through the public
+    ``jax.monitoring`` listener; compiles and persistent-cache loads are
+    counted by ``repro.obs.xla_watch.Watch``."""
 
     def __init__(self):
         import jax
+
+        from repro.obs.xla_watch import Watch
         self.seconds = 0.0
-        self.cache_hits = 0
-        self.cache_misses = 0
+        self.watch = Watch().start()
         jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
 
     def _duration(self, event, duration, **_):
         if event.startswith("/jax/core/compile/"):
             self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
 
 
 def train_config(n, family, p, channel, shards=None):
@@ -234,8 +229,8 @@ def main(argv=None) -> int:
             run_phase(clock, kind, *phase)
         check_broadcast_select(kind)
     print(json.dumps({"phase": "compile_cache", "dir": cache_dir,
-                      "hits": clock.cache_hits,
-                      "misses": clock.cache_misses}), flush=True)
+                      "cache_loads": clock.watch.cache_loads,
+                      "compiles": clock.watch.compiles}), flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": platform, "kind": kind, "count": len(devices)}}))
     return 0

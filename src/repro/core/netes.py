@@ -153,29 +153,41 @@ def netes_step(state: NetESState, adj: jax.Array, reward_fn: Callable,
     return value: ``(state'[, chan_state'], metrics_state', metrics)``.
     """
     n, dim = state.thetas.shape
-    key, k_eps, k_eval, k_beta = jax.random.split(state.key, 4)
-
-    eps = jax.random.normal(k_eps, (n, dim), dtype=state.thetas.dtype)
+    # Named scopes (DESIGN.md §15) put each part's name in its ops'
+    # metadata, so a device trace reads layer time in place; they are
+    # metadata only and change no numerics.
+    # The perturbed parameters θ ± σε count as noise: XLA fuses the
+    # threefry draw into the fusion that writes them.
+    with jax.named_scope("noise"):
+        key, k_eps, k_eval, k_beta = jax.random.split(state.key, 4)
+        eps = jax.random.normal(k_eps, (n, dim), dtype=state.thetas.dtype)
     if cfg.antithetic:
         # evaluate ±ε; fold the pair back into a single effective sample by
         # using the return difference (standard mirrored-sampling estimator).
-        pert_pos = state.thetas + cfg.sigma * eps
-        pert_neg = state.thetas - cfg.sigma * eps
-        r_pos = reward_fn(pert_pos, k_eval)
-        r_neg = reward_fn(pert_neg, k_eval)
-        raw = jnp.concatenate([r_pos, r_neg])
-        shaped_all = shape_fitness(raw, cfg.fitness_shaping)
-        shaped = shaped_all[:n] - shaped_all[n:]          # antithetic diff
+        with jax.named_scope("noise"):
+            pert_pos = state.thetas + cfg.sigma * eps
+            pert_neg = state.thetas - cfg.sigma * eps
+        with jax.named_scope("reward"):
+            r_pos = reward_fn(pert_pos, k_eval)
+            r_neg = reward_fn(pert_neg, k_eval)
+        with jax.named_scope("shaping"):
+            raw = jnp.concatenate([r_pos, r_neg])
+            shaped_all = shape_fitness(raw, cfg.fitness_shaping)
+            shaped = shaped_all[:n] - shaped_all[n:]      # antithetic diff
         # broadcast/eval track the FULL population: both ±ε halves compete
         # for argmax (the −ε half is half the samples; dropping it biased
         # best_theta/best_reward toward +ε draws).
         rewards = raw
-        candidates = jnp.concatenate([pert_pos, pert_neg])
+        with jax.named_scope("broadcast"):
+            candidates = jnp.concatenate([pert_pos, pert_neg])
         perturbed = pert_pos
     else:
-        perturbed = state.thetas + cfg.sigma * eps
-        rewards = reward_fn(perturbed, k_eval)
-        shaped = shape_fitness(rewards, cfg.fitness_shaping)
+        with jax.named_scope("noise"):
+            perturbed = state.thetas + cfg.sigma * eps
+        with jax.named_scope("reward"):
+            rewards = reward_fn(perturbed, k_eval)
+        with jax.named_scope("shaping"):
+            shaped = shape_fitness(rewards, cfg.fitness_shaping)
         candidates = perturbed
 
     # ---- lossy channel (DESIGN.md §11): encode the per-source payload,
@@ -187,76 +199,86 @@ def netes_step(state: NetESState, adj: jax.Array, reward_fn: Callable,
     # compiled scan is branch-free either way.
     wire, edge_mask, chan_info = perturbed, None, None
     if channel is not None:
-        topo = topology_repr.as_topology(adj)
-        chan_apply = (channel.apply_wire if channel.wire_fused(topo)
-                      else channel.apply)
-        wire, edge_mask, chan_state, chan_info = chan_apply(
-            chan_state, topo, perturbed)
+        with jax.named_scope("channel"):
+            topo = topology_repr.as_topology(adj)
+            chan_apply = (channel.apply_wire if channel.wire_fused(topo)
+                          else channel.apply)
+            wire, edge_mask, chan_state, chan_info = chan_apply(
+                chan_state, topo, perturbed)
 
-    update = mixing_update(adj, state.thetas, wire, shaped, cfg,
-                           edge_mask=edge_mask)
-    update = es_utils.apply_weight_decay(state.thetas, update, cfg.weight_decay)
-    new_thetas = state.thetas + update
+    with jax.named_scope("mixing"):
+        update = mixing_update(adj, state.thetas, wire, shaped, cfg,
+                               edge_mask=edge_mask)
+        update = es_utils.apply_weight_decay(state.thetas, update,
+                                             cfg.weight_decay)
+        new_thetas = state.thetas + update
 
     # ---- broadcast event (exploit) ----
-    best_idx = jnp.argmax(rewards)
-    iter_best_theta = candidates[best_idx]
-    iter_best_reward = rewards[best_idx]
-    beta = jax.random.uniform(k_beta)
-    do_broadcast = beta < cfg.p_broadcast
-    # the broadcast payload rides the same wire: lossy codecs apply
-    # (the receivers adopt the DEGRADED best — what they actually got);
-    # eval/best_theta bookkeeping keeps the true argmax parameters.
-    if (channel is not None and channel.fused and channel.wire_quantized):
-        # fused variant: decode-where-flagged in one pass over θ — the
-        # decoded (D,) + broadcast (N, D) round-trip never materializes
-        from repro.kernels import netes_fused_mixing as _nfm
-        wp = channel.encode_wire(iter_best_theta, batched=False)
-        new_thetas = _nfm.fused_broadcast_select(
-            wp.codes, wp.scale, do_broadcast, new_thetas)
-    else:
-        bcast_theta = (iter_best_theta if channel is None
-                       else channel.codec(iter_best_theta, batched=False))
-        new_thetas = jnp.where(do_broadcast,
-                               jnp.broadcast_to(bcast_theta,
-                                                new_thetas.shape),
-                               new_thetas)
+    with jax.named_scope("broadcast"):
+        best_idx = jnp.argmax(rewards)
+        iter_best_theta = candidates[best_idx]
+        iter_best_reward = rewards[best_idx]
+        beta = jax.random.uniform(k_beta)
+        do_broadcast = beta < cfg.p_broadcast
+        # the broadcast payload rides the same wire: lossy codecs apply
+        # (the receivers adopt the DEGRADED best — what they actually
+        # got); eval/best_theta bookkeeping keeps the true argmax
+        # parameters.
+        if (channel is not None and channel.fused
+                and channel.wire_quantized):
+            # fused variant: decode-where-flagged in one pass over θ — the
+            # decoded (D,) + broadcast (N, D) round-trip never materializes
+            from repro.kernels import netes_fused_mixing as _nfm
+            wp = channel.encode_wire(iter_best_theta, batched=False)
+            new_thetas = _nfm.fused_broadcast_select(
+                wp.codes, wp.scale, do_broadcast, new_thetas)
+        else:
+            bcast_theta = (iter_best_theta if channel is None
+                           else channel.codec(iter_best_theta,
+                                              batched=False))
+            new_thetas = jnp.where(do_broadcast,
+                                   jnp.broadcast_to(bcast_theta,
+                                                    new_thetas.shape),
+                                   new_thetas)
 
-    better = iter_best_reward > state.best_reward
-    new_state = NetESState(
-        thetas=new_thetas,
-        key=key,
-        step=state.step + 1,
-        best_reward=jnp.where(better, iter_best_reward, state.best_reward),
-        best_theta=jnp.where(better, iter_best_theta, state.best_theta),
-    )
-    metrics = {
-        "reward_mean": rewards.mean(),
-        "reward_max": rewards.max(),
-        "reward_min": rewards.min(),
-        "reward_std": rewards.std(),                   # fitness dispersion
-        "update_var": jnp.var(update, axis=0).sum(),   # Thm 7.1 LHS proxy
-        "broadcast": do_broadcast.astype(jnp.float32),
-        "theta_spread": jnp.var(new_thetas, axis=0).sum(),
-    }
-    if channel is not None:
-        # broadcast is one message fanned out to the population
-        bcast_msgs = do_broadcast.astype(jnp.float32) * n
-        msgs = chan_info["msgs"] + bcast_msgs
-        chan_state = chan_state._replace(msgs=chan_state.msgs + bcast_msgs)
-        metrics["msgs"] = msgs
-        metrics["trigger_frac"] = chan_info["trigger_frac"]
-        metrics["drop_frac"] = chan_info["drop_frac"]
+        better = iter_best_reward > state.best_reward
+        new_state = NetESState(
+            thetas=new_thetas,
+            key=key,
+            step=state.step + 1,
+            best_reward=jnp.where(better, iter_best_reward,
+                                  state.best_reward),
+            best_theta=jnp.where(better, iter_best_theta, state.best_theta),
+        )
+    with jax.named_scope("stats"):
+        metrics = {
+            "reward_mean": rewards.mean(),
+            "reward_max": rewards.max(),
+            "reward_min": rewards.min(),
+            "reward_std": rewards.std(),                 # fitness dispersion
+            "update_var": jnp.var(update, axis=0).sum(),  # Thm 7.1 LHS proxy
+            "broadcast": do_broadcast.astype(jnp.float32),
+            "theta_spread": jnp.var(new_thetas, axis=0).sum(),
+        }
+        if channel is not None:
+            # broadcast is one message fanned out to the population
+            bcast_msgs = do_broadcast.astype(jnp.float32) * n
+            msgs = chan_info["msgs"] + bcast_msgs
+            chan_state = chan_state._replace(
+                msgs=chan_state.msgs + bcast_msgs)
+            metrics["msgs"] = msgs
+            metrics["trigger_frac"] = chan_info["trigger_frac"]
+            metrics["drop_frac"] = chan_info["drop_frac"]
+            if probes is not None:
+                metrics_state = probes.record(
+                    metrics_state, metrics, topology_repr.as_topology(adj))
+                return new_state, chan_state, metrics_state, metrics
+            return new_state, chan_state, metrics
         if probes is not None:
             metrics_state = probes.record(
                 metrics_state, metrics, topology_repr.as_topology(adj))
-            return new_state, chan_state, metrics_state, metrics
-        return new_state, chan_state, metrics
-    if probes is not None:
-        metrics_state = probes.record(
-            metrics_state, metrics, topology_repr.as_topology(adj))
-        return new_state, metrics_state, metrics
-    return new_state, metrics
+            return new_state, metrics_state, metrics
+        return new_state, metrics
 
 
 @partial(jax.jit,
@@ -347,6 +369,12 @@ def run(state: NetESState, adj: jax.Array, reward_fn: Callable,
 # scheduled (time-varying) topologies — DESIGN.md §9
 # ---------------------------------------------------------------------------
 
+def _advance(schedule, sched_state):
+    """The topology schedule's on-device step, under its named scope."""
+    with jax.named_scope("schedule"):
+        return schedule.advance(sched_state)
+
+
 @partial(jax.jit,
          static_argnames=("reward_fn", "cfg", "schedule", "channel",
                           "probes"))
@@ -365,19 +393,19 @@ def scheduled_step(state: NetESState, sched_state, reward_fn: Callable,
             state, chan_state, metrics_state, metrics = netes_step(
                 state, sched_state.topo, reward_fn, cfg, channel,
                 chan_state, probes, metrics_state)
-            return (state, schedule.advance(sched_state), chan_state,
+            return (state, _advance(schedule, sched_state), chan_state,
                     metrics_state, metrics)
         state, chan_state, metrics = netes_step(
             state, sched_state.topo, reward_fn, cfg, channel, chan_state)
-        return state, schedule.advance(sched_state), chan_state, metrics
+        return state, _advance(schedule, sched_state), chan_state, metrics
     if probes is not None:
         state, metrics_state, metrics = netes_step(
             state, sched_state.topo, reward_fn, cfg,
             probes=probes, metrics_state=metrics_state)
-        return (state, schedule.advance(sched_state), metrics_state,
+        return (state, _advance(schedule, sched_state), metrics_state,
                 metrics)
     state, metrics = netes_step(state, sched_state.topo, reward_fn, cfg)
-    return state, schedule.advance(sched_state), metrics
+    return state, _advance(schedule, sched_state), metrics
 
 
 @partial(jax.jit,
@@ -392,7 +420,7 @@ def _run_scheduled_jit(state: NetESState, sched_state,
             s, ss, cs, ms = carry
             s, cs, ms, m = netes_step(s, ss.topo, reward_fn, cfg,
                                       channel, cs, probes, ms)
-            return (s, schedule.advance(ss), cs, ms), m
+            return (s, _advance(schedule, ss), cs, ms), m
 
         (state, sched_state, chan_state, metrics_state), metrics = \
             jax.lax.scan(cpbody,
@@ -404,7 +432,7 @@ def _run_scheduled_jit(state: NetESState, sched_state,
         def cbody(carry, _):
             s, ss, cs = carry
             s, cs, m = netes_step(s, ss.topo, reward_fn, cfg, channel, cs)
-            return (s, schedule.advance(ss), cs), m
+            return (s, _advance(schedule, ss), cs), m
 
         (state, sched_state, chan_state), metrics = jax.lax.scan(
             cbody, (state, sched_state, chan_state), None,
@@ -416,7 +444,7 @@ def _run_scheduled_jit(state: NetESState, sched_state,
             s, ss, ms = carry
             s, ms, m = netes_step(s, ss.topo, reward_fn, cfg,
                                   probes=probes, metrics_state=ms)
-            return (s, schedule.advance(ss), ms), m
+            return (s, _advance(schedule, ss), ms), m
 
         (state, sched_state, metrics_state), metrics = jax.lax.scan(
             pbody, (state, sched_state, metrics_state), None,
@@ -426,7 +454,7 @@ def _run_scheduled_jit(state: NetESState, sched_state,
     def body(carry, _):
         s, ss = carry
         s, m = netes_step(s, ss.topo, reward_fn, cfg)
-        return (s, schedule.advance(ss)), m
+        return (s, _advance(schedule, ss)), m
 
     (state, sched_state), metrics = jax.lax.scan(
         body, (state, sched_state), None, length=num_iters)

@@ -472,9 +472,11 @@ class ShardedNetES:
             edge_mask = None
             wire = pert_full
             if chan is not None:
-                chan_apply = (chan.apply_wire if chan.wire_fused(topo)
-                              else chan.apply)
-                wire, edge_mask, cs, info = chan_apply(cs, topo, pert_full)
+                with jax.named_scope("channel"):
+                    chan_apply = (chan.apply_wire if chan.wire_fused(topo)
+                                  else chan.apply)
+                    wire, edge_mask, cs, info = chan_apply(cs, topo,
+                                                           pert_full)
                 chan_metrics = info
             wnb = topology_repr.weighted_neighbor_sum(
                 topo, shaped, wire, edge_mask=edge_mask)
@@ -490,7 +492,8 @@ class ShardedNetES:
             deg = jax.lax.dynamic_slice_in_dim(deg, lo, n_loc, 0)
             return mixed, wsum, deg, cs, chan_metrics
 
-        parts, decode = self._encode_payload(pert_pos)
+        with jax.named_scope("channel"):
+            parts, decode = self._encode_payload(pert_pos)
 
         if plan.mode == "halo":
             bufs = [list(parts)]
@@ -537,22 +540,25 @@ class ShardedNetES:
         n, n_loc, n_pad = plan.n, plan.n_loc, plan.n_pad
         th = carry["th"]
         d = th.shape[1]
-        key, k_eps, k_eval, k_beta = jax.random.split(carry["key"], 4)
         lo = ops.axis_index() * n_loc
         gid = lo + jnp.arange(n_loc, dtype=jnp.int32)
         valid = (gid < n).astype(th.dtype)
 
-        # placement-invariant per-agent noise (the netes_dist idiom):
-        # agent g's ε is a pure function of (k_eps, g).
-        eps = jax.vmap(lambda g: jax.random.normal(
-            jax.random.fold_in(k_eps, g), (d,), dtype=th.dtype))(gid)
-        # Round σ·ε before the add: XLA is free to contract mul+add
-        # chains into FMAs, and it decides per compiled program — the
-        # (n_loc, D) and (N, D) programs can disagree in the last ulp.
-        # optimization_barrier pins the rounding points so every mesh
-        # size adds bit-identical values (shard-invariance contract).
-        s_eps = jax.lax.optimization_barrier(cfg.sigma * eps)
-        pert_pos = th + s_eps
+        # The parts carry core.netes.netes_step's named scopes (DESIGN.md
+        # §15): op metadata only, no change to numerics or fusion.
+        with jax.named_scope("noise"):
+            key, k_eps, k_eval, k_beta = jax.random.split(carry["key"], 4)
+            # placement-invariant per-agent noise (the netes_dist idiom):
+            # agent g's ε is a pure function of (k_eps, g).
+            eps = jax.vmap(lambda g: jax.random.normal(
+                jax.random.fold_in(k_eps, g), (d,), dtype=th.dtype))(gid)
+            # Round σ·ε before the add: XLA is free to contract mul+add
+            # chains into FMAs, and it decides per compiled program — the
+            # (n_loc, D) and (N, D) programs can disagree in the last ulp.
+            # optimization_barrier pins the rounding points so every mesh
+            # size adds bit-identical values (shard-invariance contract).
+            s_eps = jax.lax.optimization_barrier(cfg.sigma * eps)
+            pert_pos = th + s_eps
 
         def rewards(params):
             # A reward with per-row keys (envs.rollout) gets agent g's
@@ -566,64 +572,73 @@ class ShardedNetES:
             return rowwise(params, keys)
 
         if cfg.antithetic:
-            pert_neg = th - s_eps
-            r_pos = ops.all_gather(rewards(pert_pos))[:n]
-            r_neg = ops.all_gather(rewards(pert_neg))[:n]
-            raw = jnp.concatenate([r_pos, r_neg])
-            shaped_all = netes.shape_fitness(raw, cfg.fitness_shaping)
-            shaped = shaped_all[:n] - shaped_all[n:]
+            with jax.named_scope("noise"):
+                pert_neg = th - s_eps
+            with jax.named_scope("reward"):
+                r_pos = ops.all_gather(rewards(pert_pos))[:n]
+                r_neg = ops.all_gather(rewards(pert_neg))[:n]
+            with jax.named_scope("shaping"):
+                raw = jnp.concatenate([r_pos, r_neg])
+                shaped_all = netes.shape_fitness(raw, cfg.fitness_shaping)
+                shaped = shaped_all[:n] - shaped_all[n:]
         else:
-            raw = ops.all_gather(rewards(pert_pos))[:n]
-            shaped = netes.shape_fitness(raw, cfg.fitness_shaping)
-        shaped_pad = jnp.pad(shaped, (0, n_pad - n))
+            with jax.named_scope("reward"):
+                raw = ops.all_gather(rewards(pert_pos))[:n]
+            with jax.named_scope("shaping"):
+                shaped = netes.shape_fitness(raw, cfg.fitness_shaping)
+        with jax.named_scope("shaping"):
+            shaped_pad = jnp.pad(shaped, (0, n_pad - n))
 
-        mixed, wsum, deg, cs, chan_metrics = self._mix(
-            ops, operands, th, pert_pos, shaped, shaped_pad, carry)
-        # Same FMA-seam pinning as σ·ε above: round every product before
-        # it enters an add/sub so the update chain is bitwise identical
-        # across program shapes (solo vs any mesh size).
-        mixed, wsum = jax.lax.optimization_barrier((mixed, wsum))
-        mixed = mixed - jax.lax.optimization_barrier(wsum[:, None] * th)
-        if cfg.normalization == "degree":
-            scale = cfg.alpha / (deg[:, None] * cfg.sigma ** 2)
-        else:
-            scale = cfg.alpha / (n * cfg.sigma ** 2)
-        update = jax.lax.optimization_barrier(scale * mixed)
-        if cfg.weight_decay:
-            # es_utils.apply_weight_decay semantics (u ← u − wd·θ) with
-            # the wd·θ product rounded before the subtract.
-            update = jax.lax.optimization_barrier(
-                update - jax.lax.optimization_barrier(
-                    cfg.weight_decay * th))
-        new_th = th + update
+        with jax.named_scope("mixing"):
+            mixed, wsum, deg, cs, chan_metrics = self._mix(
+                ops, operands, th, pert_pos, shaped, shaped_pad, carry)
+            # Same FMA-seam pinning as σ·ε above: round every product
+            # before it enters an add/sub so the update chain is bitwise
+            # identical across program shapes (solo vs any mesh size).
+            mixed, wsum = jax.lax.optimization_barrier((mixed, wsum))
+            mixed = mixed - jax.lax.optimization_barrier(wsum[:, None] * th)
+            if cfg.normalization == "degree":
+                scale = cfg.alpha / (deg[:, None] * cfg.sigma ** 2)
+            else:
+                scale = cfg.alpha / (n * cfg.sigma ** 2)
+            update = jax.lax.optimization_barrier(scale * mixed)
+            if cfg.weight_decay:
+                # es_utils.apply_weight_decay semantics (u ← u − wd·θ)
+                # with the wd·θ product rounded before the subtract.
+                update = jax.lax.optimization_barrier(
+                    update - jax.lax.optimization_barrier(
+                        cfg.weight_decay * th))
+            new_th = th + update
 
         # ---- broadcast event: fetch the argmax row via a masked psum
         # (zeros + the owner's row — exact, order-free).
-        best_idx = jnp.argmax(raw)
-        iter_best_reward = raw[best_idx]
-        b0 = best_idx % n if cfg.antithetic else best_idx
-        row_idx = jnp.clip(b0 - lo, 0, n_loc - 1)
-        row = jax.lax.dynamic_index_in_dim(pert_pos, row_idx, 0,
-                                           keepdims=False)
-        if cfg.antithetic:
-            row_neg = jax.lax.dynamic_index_in_dim(pert_neg, row_idx, 0,
-                                                   keepdims=False)
-            row = jnp.where(best_idx < n, row, row_neg)
-        mine = ((b0 >= lo) & (b0 < lo + n_loc)).astype(th.dtype)
-        iter_best_theta = ops.psum(row * mine)
-        beta = jax.random.uniform(k_beta)
-        do_b = beta < cfg.p_broadcast
-        bcast = iter_best_theta if chan is None else chan.codec(
-            iter_best_theta, batched=False)
-        new_th = jnp.where(do_b, jnp.broadcast_to(bcast, new_th.shape),
-                           new_th)
+        with jax.named_scope("broadcast"):
+            best_idx = jnp.argmax(raw)
+            iter_best_reward = raw[best_idx]
+            b0 = best_idx % n if cfg.antithetic else best_idx
+            row_idx = jnp.clip(b0 - lo, 0, n_loc - 1)
+            row = jax.lax.dynamic_index_in_dim(pert_pos, row_idx, 0,
+                                               keepdims=False)
+            if cfg.antithetic:
+                row_neg = jax.lax.dynamic_index_in_dim(pert_neg, row_idx, 0,
+                                                       keepdims=False)
+                row = jnp.where(best_idx < n, row, row_neg)
+            mine = ((b0 >= lo) & (b0 < lo + n_loc)).astype(th.dtype)
+            iter_best_theta = ops.psum(row * mine)
+            beta = jax.random.uniform(k_beta)
+            do_b = beta < cfg.p_broadcast
+            bcast = iter_best_theta if chan is None else chan.codec(
+                iter_best_theta, batched=False)
+            new_th = jnp.where(do_b, jnp.broadcast_to(bcast, new_th.shape),
+                               new_th)
 
-        better = iter_best_reward > carry["best_r"]
-        out = dict(carry)
-        out.update(
-            th=new_th, key=key, step=carry["step"] + 1,
-            best_r=jnp.where(better, iter_best_reward, carry["best_r"]),
-            best_th=jnp.where(better, iter_best_theta, carry["best_th"]))
+            better = iter_best_reward > carry["best_r"]
+            out = dict(carry)
+            out.update(
+                th=new_th, key=key, step=carry["step"] + 1,
+                best_r=jnp.where(better, iter_best_reward, carry["best_r"]),
+                best_th=jnp.where(better, iter_best_theta,
+                                  carry["best_th"]))
 
         def spread(x):
             # cross-shard population variance over the N valid rows via
@@ -632,40 +647,45 @@ class ShardedNetES:
             s2 = ops.psum((valid[:, None] * x * x).sum(axis=0))
             return ((s2 / n) - (s1 / n) ** 2).sum()
 
-        # the gathered (N,) rewards are fused into the reductions below
-        # differently by each program shape; materialize them first so
-        # every mesh size (and solo) sums them in the same order
-        raw = jax.lax.optimization_barrier(raw)
-        metrics = {
-            "reward_mean": raw.mean(),
-            "reward_max": raw.max(),
-            "reward_min": raw.min(),
-            "reward_std": raw.std(),   # fitness dispersion (global: raw
-            "update_var": spread(update),       # is the gathered array)
-            "broadcast": do_b.astype(jnp.float32),
-            "theta_spread": spread(new_th),
-        }
-        if chan is not None:
-            bcast_msgs = do_b.astype(jnp.float32) * n
-            if chan_metrics is None:  # stateless codec modes
-                mix_msgs = jnp.float32(self._static_msgs)
-                metrics["trigger_frac"] = jnp.ones((), jnp.float32)
-                metrics["drop_frac"] = jnp.zeros((), jnp.float32)
-            else:
-                mix_msgs = chan_metrics["msgs"]
-                metrics["trigger_frac"] = chan_metrics["trigger_frac"]
-                metrics["drop_frac"] = chan_metrics["drop_frac"]
-            metrics["msgs"] = mix_msgs + bcast_msgs
-            out["cs"] = cs._replace(msgs=cs.msgs + mix_msgs + bcast_msgs)
+        with jax.named_scope("stats"):
+            # the gathered (N,) rewards are fused into the reductions
+            # below differently by each program shape; materialize them
+            # first so every mesh size (and solo) sums them in the same
+            # order
+            raw = jax.lax.optimization_barrier(raw)
+            metrics = {
+                "reward_mean": raw.mean(),
+                "reward_max": raw.max(),
+                "reward_min": raw.min(),
+                "reward_std": raw.std(),   # fitness dispersion (global: raw
+                "update_var": spread(update),       # is the gathered array)
+                "broadcast": do_b.astype(jnp.float32),
+                "theta_spread": spread(new_th),
+            }
+            if chan is not None:
+                bcast_msgs = do_b.astype(jnp.float32) * n
+                if chan_metrics is None:  # stateless codec modes
+                    mix_msgs = jnp.float32(self._static_msgs)
+                    metrics["trigger_frac"] = jnp.ones((), jnp.float32)
+                    metrics["drop_frac"] = jnp.zeros((), jnp.float32)
+                else:
+                    mix_msgs = chan_metrics["msgs"]
+                    metrics["trigger_frac"] = chan_metrics["trigger_frac"]
+                    metrics["drop_frac"] = chan_metrics["drop_frac"]
+                metrics["msgs"] = mix_msgs + bcast_msgs
+                out["cs"] = cs._replace(
+                    msgs=cs.msgs + mix_msgs + bcast_msgs)
         if self.schedule is not None:
-            out["ss"] = self.schedule.advance(carry["ss"])
+            with jax.named_scope("schedule"):
+                out["ss"] = self.schedule.advance(carry["ss"])
         if self.probes is not None:
             # graph probes read the LIVE topology (pre-advance, matching
             # core.netes.scheduled_step); FullyConnected has no Topology
             # to read — its graph stage fails at trace time by design.
             live = carry["ss"].topo if self.schedule is not None else (
                 self.topo if isinstance(self.topo, Topology) else None)
-            out["ms"] = self.probes.record(carry["ms"], metrics, live)
+            with jax.named_scope("stats"):
+                out["ms"] = self.probes.record(carry["ms"], metrics, live)
         return out, metrics
 
     # -- jitted run --------------------------------------------------------

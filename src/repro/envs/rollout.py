@@ -64,7 +64,9 @@ def evaluate_best(env, policy: MLPPolicy, theta: jax.Array, key: jax.Array,
                   episodes: int = 32) -> jax.Array:
     """Paper's evaluation metric: run best params w/o noise for many
     episodes, return mean total reward (§5.2; 1000 episodes in the paper,
-    reduced here)."""
-    keys = jax.random.split(key, episodes)
-    rets = jax.vmap(partial(episode_return, env, policy, theta))(keys)
-    return rets.mean()
+    reduced here). Its ops carry the ``eval`` named scope (DESIGN.md
+    §15)."""
+    with jax.named_scope("eval"):
+        keys = jax.random.split(key, episodes)
+        rets = jax.vmap(partial(episode_return, env, policy, theta))(keys)
+        return rets.mean()

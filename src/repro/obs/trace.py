@@ -6,16 +6,26 @@ every following line is a ``span`` or ``event`` record:
 
     {"kind": "meta", "schema": "repro.trace/v1", "name": ..., env...}
     {"kind": "span", "name": "chunk", "t0": ..., "dur_s": ...,
-     "compiles": 0, "transfers": 1, "attrs": {...}}
+     "compiles": 0, "transfers": 1, "cache_loads": 0, "attrs": {...}}
     {"kind": "event", "name": "eval", "t": ..., "attrs": {...}}
 
-Spans are wall-time intervals (compile / warmup / chunk / drain / ...)
-stamped with the XLA compile count and host-transfer count that occurred
-INSIDE the span (via ``xla_watch.Watch``) — so "which chunk recompiled"
-and "which drain double-transferred" are greppable facts, not printf
-archaeology. Spans may nest; each line is self-contained (``depth``
+Spans are wall-time intervals (build / chunk / step / eval / drain /
+checkpoint in the training loop, prefill / decode in the server)
+stamped with the XLA compiles, host transfers and persistent-cache
+loads that occurred INSIDE the span (via ``xla_watch.Watch``) — so
+"which chunk recompiled" and "which drain double-transferred" are
+greppable facts, not printf archaeology. ``compiles`` counts backend
+compilations the persistent cache did not serve; ``cache_loads`` (an
+optional key of ``repro.trace/v1``, absent from older traces) the
+programs it did. Spans may nest; each line is self-contained (``depth``
 records nesting). The writer never touches device values itself: probes
 drain through ``Probes.drain``, the trace only records host-side timing.
+
+Every span also opens ``jax.profiler.TraceAnnotation("repro/<name>")``,
+with or without a file (``Trace(None)`` included), so a profiler trace
+holds the same spans on its host plane, on the device planes' clock.
+With no profiler running that is one TraceMe check per span, well under
+a microsecond; the training loop opens a few spans per chunk.
 
 ``validate_trace`` is the schema gate CI runs (``python -m repro.obs
 validate <file>``); ``summarize`` renders a per-span table.
@@ -27,6 +37,8 @@ import json
 import pathlib
 import time
 from typing import Any, Dict, Iterator, List, Optional
+
+import jax
 
 SCHEMA = "repro.trace/v1"
 
@@ -45,8 +57,9 @@ class Trace:
             tr.event("eval", score=1.2)
 
     Lines are flushed per record (a crashed run keeps its prefix; every
-    prefix is a valid trace). ``Trace(None)`` is a no-op writer so call
-    sites thread ``trace`` unconditionally without ``if`` forests.
+    prefix is a valid trace). ``Trace(None)`` writes no file, so call
+    sites thread ``trace`` unconditionally without ``if`` forests; its
+    spans still reach the profiler as ``repro/<name>`` annotations.
     """
 
     def __init__(self, path: Optional[str | pathlib.Path],
@@ -61,7 +74,6 @@ class Trace:
         self.path.parent.mkdir(parents=True, exist_ok=True)
         self._fh = self.path.open("w")
         self._watch = xla_watch.Watch().start()
-        import jax
         self._write({"kind": "meta", "schema": SCHEMA, "name": name,
                      "t0": time.time(), "jax": jax.__version__,
                      "backend": jax.default_backend(),
@@ -91,25 +103,28 @@ class Trace:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs: Any) -> Iterator[None]:
-        """Wall-time span stamped with the XLA compiles and host
-        transfers that happened inside it."""
-        if self._fh is None:
-            yield
-            return
-        c0, x0 = self._watch.snapshot()
-        t0 = time.time()
-        self._depth += 1
-        try:
-            yield
-        finally:
-            self._depth -= 1
-            c1, x1 = self._watch.snapshot()
-            rec = {"kind": "span", "name": name, "t0": t0,
-                   "dur_s": time.time() - t0, "depth": self._depth,
-                   "compiles": c1 - c0, "transfers": x1 - x0}
-            if attrs:
-                rec["attrs"] = attrs
-            self._write(rec)
+        """Wall-time span stamped with the XLA compiles, host transfers
+        and cache loads that happened inside it, and the profiler
+        annotation ``repro/<name>`` around it."""
+        with jax.profiler.TraceAnnotation(f"repro/{name}"):
+            if self._fh is None:
+                yield
+                return
+            c0, x0, l0 = self._watch.snapshot()
+            t0 = time.time()
+            self._depth += 1
+            try:
+                yield
+            finally:
+                self._depth -= 1
+                c1, x1, l1 = self._watch.snapshot()
+                rec = {"kind": "span", "name": name, "t0": t0,
+                       "dur_s": time.time() - t0, "depth": self._depth,
+                       "compiles": c1 - c0, "transfers": x1 - x0,
+                       "cache_loads": l1 - l0}
+                if attrs:
+                    rec["attrs"] = attrs
+                self._write(rec)
 
     def event(self, name: str, **attrs: Any) -> None:
         if self._fh is None:
@@ -174,14 +189,15 @@ def validate_trace(path: str | pathlib.Path) -> List[str]:
         for k in ("t0", "dur_s", "t"):
             if k in rec and not isinstance(rec[k], (int, float)):
                 errors.append(f"line {i}: {k} must be a number")
-        for k in ("compiles", "transfers", "depth"):
+        for k in ("compiles", "transfers", "depth", "cache_loads"):
             if k in rec and (not isinstance(rec[k], int) or rec[k] < 0):
                 errors.append(f"line {i}: {k} must be a non-negative int")
     return errors
 
 
 def summarize(path: str | pathlib.Path) -> str:
-    """Per-span-name aggregate: count, total wall, compiles, transfers."""
+    """Per-span-name aggregate: count, total wall, compiles, transfers,
+    cache loads."""
     recs = read_trace(path)
     meta = recs[0] if recs and recs[0].get("kind") == "meta" else {}
     spans: Dict[str, Dict[str, float]] = {}
@@ -193,19 +209,21 @@ def summarize(path: str | pathlib.Path) -> str:
         if rec.get("kind") != "span":
             continue
         agg = spans.setdefault(rec["name"], {"n": 0, "wall_s": 0.0,
-                                             "compiles": 0, "transfers": 0})
+                                             "compiles": 0, "transfers": 0,
+                                             "cache_loads": 0})
         agg["n"] += 1
         agg["wall_s"] += rec.get("dur_s", 0.0)
-        agg["compiles"] += rec.get("compiles", 0)
-        agg["transfers"] += rec.get("transfers", 0)
+        for k in ("compiles", "transfers", "cache_loads"):
+            agg[k] += rec.get(k, 0)
     lines = [f"trace {meta.get('name', '?')} — schema "
              f"{meta.get('schema', '?')}, jax {meta.get('jax', '?')}, "
              f"{meta.get('devices', '?')} device(s)"]
     lines.append(f"{'span':<16}{'n':>6}{'wall_s':>10}{'compiles':>10}"
-                 f"{'transfers':>11}")
+                 f"{'transfers':>11}{'cache_loads':>13}")
     for name in sorted(spans):
         a = spans[name]
         lines.append(f"{name:<16}{a['n']:>6}{a['wall_s']:>10.3f}"
-                     f"{a['compiles']:>10}{a['transfers']:>11}")
+                     f"{a['compiles']:>10}{a['transfers']:>11}"
+                     f"{a['cache_loads']:>13}")
     lines.append(f"{events} event(s), {len(recs) - 1} record(s)")
     return "\n".join(lines)

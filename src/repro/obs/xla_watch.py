@@ -19,7 +19,8 @@ Two kinds of hook:
 Both are context managers yielding a list that grows by one per event,
 so ``len(...)`` is the count and the list identity can be captured
 before entering jitted code. ``Watch`` is the persistent variant the
-JSONL trace writer uses to stamp per-span compile/transfer deltas.
+JSONL trace writer uses to stamp per-span compile, cache-load and
+transfer deltas; it counts compiles net of persistent-cache hits.
 """
 from __future__ import annotations
 
@@ -30,6 +31,7 @@ from typing import Iterator, List
 import jax
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # -- host-transfer hook ------------------------------------------------------
 # One module-level wrapper around jax.device_get serves every active
@@ -105,23 +107,39 @@ def count_host_transfers() -> Iterator[List[str]]:
 
 
 class Watch:
-    """Persistent compile+transfer counter for span-structured tracing.
+    """Persistent compile, cache-load and transfer counter for
+    span-structured tracing.
 
-    ``start()`` installs both hooks; ``snapshot()`` returns monotonic
-    ``(compiles, transfers)`` totals so a span records deltas around its
-    body; ``stop()`` uninstalls. Used by ``repro.obs.trace.Trace`` —
-    every span line carries the compiles/transfers that happened inside
-    it (satisfying the "emit both counters as trace spans" contract).
+    ``start()`` installs the hooks; ``snapshot()`` returns monotonic
+    ``(compiles, transfers, cache_loads)`` totals so a span records
+    deltas around its body; ``stop()`` uninstalls. Used by
+    ``repro.obs.trace.Trace`` — every span line carries the compiles,
+    transfers and cache loads that happened inside it.
+
+    On jax 0.9 the backend-compile event also fires for a request the
+    persistent compilation cache serves, so ``compiles`` is compile
+    requests minus cache hits (``/jax/compilation_cache/cache_hits``)
+    and ``cache_loads`` the hits: a program loaded from the cache was
+    traced and lowered, not compiled.
     """
 
     def __init__(self) -> None:
-        self.compiles = 0
+        self.requests = 0
+        self.cache_loads = 0
         self.transfers = 0
         self._active = False
 
+    @property
+    def compiles(self) -> int:
+        return self.requests - self.cache_loads
+
     def _on_compile(self, event, *a, **kw):
         if event == _COMPILE_EVENT:
-            self.compiles += 1
+            self.requests += 1
+
+    def _on_event(self, event, *a, **kw):
+        if event == _CACHE_HIT_EVENT:
+            self.cache_loads += 1
 
     def _on_transfer(self):
         self.transfers += 1
@@ -131,16 +149,18 @@ class Watch:
             return self
         jax.monitoring.register_event_duration_secs_listener(
             self._on_compile)
+        jax.monitoring.register_event_listener(self._on_event)
         _add_transfer_callback(self._on_transfer)
         self._active = True
         return self
 
     def snapshot(self):
-        return self.compiles, self.transfers
+        return self.compiles, self.transfers, self.cache_loads
 
     def stop(self) -> None:
         if not self._active:
             return
         jax.monitoring.unregister_event_duration_listener(self._on_compile)
+        jax.monitoring.unregister_event_listener(self._on_event)
         _remove_transfer_callback(self._on_transfer)
         self._active = False

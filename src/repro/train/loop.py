@@ -88,8 +88,9 @@ class TrainConfig:
     # holds the LAST ``capacity`` iterations in order.
     probe_capacity: int = 0
     # Path for a structured JSONL trace (obs/trace.py): spans for every
-    # chunk/eval/drain with wall times + XLA compile and host-transfer
-    # counts. None ⇒ no trace file (zero overhead).
+    # build/chunk/eval/drain with wall times + XLA compile, cache-load and
+    # host-transfer counts. None ⇒ no trace file; the spans still reach
+    # a running profiler as ``repro/<name>`` annotations.
     trace: Optional[str] = None
     netes: NetESConfig = dataclasses.field(default_factory=NetESConfig)
 
@@ -187,26 +188,30 @@ def train_rl_netes(task: str, tc: TrainConfig,
     mid-channel-stream) bit-for-bit; a resumed run's history covers
     only the post-resume iterations.
     """
-    key = jax.random.PRNGKey(tc.seed)
-    reward_fn, dim, init_fn, env, policy = resolve_task(task)
-
-    mesh = None
-    if tc.shards is not None:
-        from repro.distributed import fleet_shard
-        mesh = fleet_shard.build_mesh(tc.shards)
-    schedule = build_schedule(tc)
-    if schedule is not None:
-        topo, sstate = None, schedule.init()
-    else:
-        topo, sstate = build_topology(tc), None
-    state = netes.init_state(key, tc.n_agents, dim, init_fn=init_fn)
-    channel = build_channel(tc)
-    cstate = channel.init(state.thetas) if channel is not None else None
-    probes = build_probes(tc, channel=channel, dim=dim)
-    mstate = probes.init() if probes is not None else None
+    # Host phases run inside ``Trace`` spans (build, chunk, step, eval,
+    # drain, checkpoint), which also reach the profiler as ``repro/<name>``
+    # annotations whether or not ``tc.trace`` names a file.
     tr = Trace(tc.trace, name=f"rl:{task}", task=task,
                n_agents=tc.n_agents, iters=tc.iters,
-               probes=probes.spec.label() if probes is not None else None)
+               probes=tc.probes.label() if tc.probes is not None else None)
+    with tr.span("build"):
+        key = jax.random.PRNGKey(tc.seed)
+        reward_fn, dim, init_fn, env, policy = resolve_task(task)
+
+        mesh = None
+        if tc.shards is not None:
+            from repro.distributed import fleet_shard
+            mesh = fleet_shard.build_mesh(tc.shards)
+        schedule = build_schedule(tc)
+        if schedule is not None:
+            topo, sstate = None, schedule.init()
+        else:
+            topo, sstate = build_topology(tc), None
+        state = netes.init_state(key, tc.n_agents, dim, init_fn=init_fn)
+        channel = build_channel(tc)
+        cstate = channel.init(state.thetas) if channel is not None else None
+        probes = build_probes(tc, channel=channel, dim=dim)
+        mstate = probes.init() if probes is not None else None
     history: Dict[str, List] = {"reward_mean": [], "reward_max": [],
                                 "eval": [], "eval_iter": []}
     if channel is not None:
@@ -232,14 +237,15 @@ def train_rl_netes(task: str, tc: TrainConfig,
         eval_iters.append(tc.iters - 1)
 
     def drain(m):
+        # one jax.device_get per chunk: the sync the trace's transfer
+        # count sees
         with tr.span("drain"):
-            history["reward_mean"].extend(
-                np.asarray(m["reward_mean"], np.float64).reshape(-1).tolist())
-            history["reward_max"].extend(
-                np.asarray(m["reward_max"], np.float64).reshape(-1).tolist())
-            if "msgs" in m:
-                history["msgs"].extend(
-                    np.asarray(m["msgs"], np.float64).reshape(-1).tolist())
+            names = [k for k in ("reward_mean", "reward_max", "msgs")
+                     if k in m]
+            host = jax.device_get({k: m[k] for k in names})
+            for k in names:
+                history[k].extend(
+                    np.asarray(host[k], np.float64).reshape(-1).tolist())
 
     eval_key = jax.random.PRNGKey(tc.seed + 999)
 
@@ -355,13 +361,14 @@ def train_rl_netes(task: str, tc: TrainConfig,
             todo -= scan_chunk
         for _ in range(todo):   # tail < scan_chunk: jitted single steps
             advance_one()
-        eval_key, k_eval = jax.random.split(eval_key)
         with tr.span("eval", iter=it):
+            eval_key, k_eval = jax.random.split(eval_key)
             if env is not None:
                 score = evaluate_best(env, policy, state.best_theta,
                                       k_eval, tc.eval_episodes)
             else:
-                score = reward_fn(state.best_theta[None], k_eval)[0]
+                with jax.named_scope("eval"):
+                    score = reward_fn(state.best_theta[None], k_eval)[0]
         eval_pending.append((it, score))
         if len(eval_pending) >= METRIC_DRAIN_CHUNK or log is not None:
             # ``log`` wants the score now (interactive runs accept the

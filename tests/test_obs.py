@@ -260,11 +260,96 @@ def test_trace_schema_and_counters(tmp_path):
     recs = [json.loads(x) for x in path.read_text().splitlines()]
     assert recs[0]["kind"] == "meta" and recs[0]["schema"] == SCHEMA
     by_name = {r["name"]: r for r in recs[1:]}
-    assert by_name["warmup"]["compiles"] >= 1
+    # a compile the persistent cache serves counts as a cache load
+    assert by_name["warmup"]["compiles"] + by_name["warmup"][
+        "cache_loads"] >= 1
     assert by_name["drain"]["transfers"] == 1
     assert by_name["eval"]["attrs"]["score"] == 1.5
     out = summarize(path)
     assert "warmup" in out and "drain" in out
+
+
+@pytest.fixture
+def warm_cache(tmp_path):
+    """The persistent compilation cache in a fresh directory, caching
+    every program; the worker's own settings come back afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+def test_watch_counts_cache_loads_apart_from_compiles(warm_cache):
+    """A program the persistent cache serves is a cache load, not a
+    compile, though jax fires its backend-compile event for both."""
+    from repro.obs import Watch
+    f = jax.jit(lambda x: x * 3.0 + 17.0)
+    x = jnp.arange(5.0)
+    w = Watch().start()
+    try:
+        f(x).block_until_ready()
+        first = w.snapshot()
+        jax.clear_caches()                  # in-memory only
+        f(x).block_until_ready()
+        second = w.snapshot()
+    finally:
+        w.stop()
+    assert first == (1, 0, 0)               # (compiles, transfers, loads)
+    assert second == (1, 0, 1)
+
+
+def test_trace_spans_stamp_cache_loads(tmp_path, warm_cache):
+    path = tmp_path / "t.jsonl"
+    f = jax.jit(lambda x: x * 5.0 - 3.0)
+    x = jnp.arange(4.0)
+    with Trace(path, name="unit") as tr:
+        with tr.span("compile"):
+            f(x).block_until_ready()
+        jax.clear_caches()
+        with tr.span("load"):
+            f(x).block_until_ready()
+    assert validate_trace(path) == []
+    by_name = {r["name"]: r for r in
+               map(json.loads, path.read_text().splitlines()[1:])}
+    assert (by_name["compile"]["compiles"],
+            by_name["compile"]["cache_loads"]) == (1, 0)
+    assert (by_name["load"]["compiles"],
+            by_name["load"]["cache_loads"]) == (0, 1)
+
+
+def test_train_drain_is_one_counted_transfer(tmp_path, warm_cache):
+    """Each drain of the training loop (chunk metrics, evaluation
+    scores) is one ``jax.device_get``, which the span's transfer count
+    sees; a second run loads its programs from the warm cache."""
+    from repro.train.loop import TrainConfig, train_rl_netes
+
+    def spans(tag):
+        path = tmp_path / f"{tag}.jsonl"
+        train_rl_netes("landscape:sphere",
+                       TrainConfig(n_agents=8, iters=6, seed=0,
+                                   eval_every=2, trace=str(path)))
+        assert validate_trace(path) == []
+        return [json.loads(x) for x in path.read_text().splitlines()[1:]]
+
+    cold = spans("cold")
+    jax.clear_caches()
+    warm = spans("warm")
+    for recs in (cold, warm):
+        drains = [r for r in recs if r["name"] == "drain"]
+        assert len(drains) == 3 + 1     # three chunks, one eval drain
+        assert all(r["transfers"] == 1 for r in drains)
+    assert sum(r["compiles"] for r in warm) == 0
+    assert sum(r["cache_loads"] for r in warm) >= 1
+    assert sum(r["compiles"] for r in cold) >= 1
 
 
 def test_trace_none_is_noop():
@@ -299,7 +384,7 @@ def test_train_trace_end_to_end_and_cli(tmp_path):
     assert validate_trace(path) == []
     names = {json.loads(x).get("name")
              for x in path.read_text().splitlines()}
-    assert {"chunk", "drain", "eval"} <= names
+    assert {"build", "chunk", "drain", "eval"} <= names
     res = subprocess.run(
         [sys.executable, "-m", "repro.obs", "summarize", str(path)],
         capture_output=True, text=True, env=_env())
