@@ -8,7 +8,7 @@ the population — the exact interface ``core.netes`` consumes.
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -70,3 +70,30 @@ def evaluate_best(env, policy: MLPPolicy, theta: jax.Array, key: jax.Array,
         keys = jax.random.split(key, episodes)
         rets = jax.vmap(partial(episode_return, env, policy, theta))(keys)
         return rets.mean()
+
+
+def make_evaluator(env, policy: Optional[MLPPolicy], episodes: int,
+                   reward_fn: Optional[Callable] = None) -> Callable:
+    """One jitted evaluation program for a whole training run:
+    ``evaluate(theta, eval_key) -> (score, next_eval_key)``.
+
+    It splits ``eval_key`` into the next key and this point's key, then
+    scores ``theta`` on the latter: ``evaluate_best``'s mean return over
+    ``episodes`` episodes, or, with ``env`` None (a landscape task),
+    ``reward_fn`` on the one row. Built once per run and called at every
+    evaluation point, it traces and compiles once; ``evaluate_best``
+    called eagerly re-traces its episode scan at every call. Every op
+    carries the ``eval`` named scope."""
+    if env is not None:
+        score = partial(evaluate_best, env, policy, episodes=episodes)
+    else:
+        def score(theta, key):
+            return reward_fn(theta[None], key)[0]
+
+    @jax.jit
+    def evaluate(theta: jax.Array, eval_key: jax.Array):
+        with jax.named_scope("eval"):
+            eval_key, k_eval = jax.random.split(eval_key)
+            return score(theta, k_eval), eval_key
+
+    return evaluate

@@ -29,7 +29,7 @@ from repro.core.topology_sched import ScheduleSpec, TopologySchedule
 from repro.data import make_batch
 from repro.distributed import netes_dist
 from repro.envs import resolve_task
-from repro.envs.rollout import evaluate_best
+from repro.envs.rollout import make_evaluator
 from repro.models import transformer
 from repro.obs import Trace
 from repro.obs.probes import (DEFAULT_CAPACITY, Probes, ProbeSpec,
@@ -212,6 +212,7 @@ def train_rl_netes(task: str, tc: TrainConfig,
         cstate = channel.init(state.thetas) if channel is not None else None
         probes = build_probes(tc, channel=channel, dim=dim)
         mstate = probes.init() if probes is not None else None
+        evaluate = make_evaluator(env, policy, tc.eval_episodes, reward_fn)
     history: Dict[str, List] = {"reward_mean": [], "reward_max": [],
                                 "eval": [], "eval_iter": []}
     if channel is not None:
@@ -362,13 +363,7 @@ def train_rl_netes(task: str, tc: TrainConfig,
         for _ in range(todo):   # tail < scan_chunk: jitted single steps
             advance_one()
         with tr.span("eval", iter=it):
-            eval_key, k_eval = jax.random.split(eval_key)
-            if env is not None:
-                score = evaluate_best(env, policy, state.best_theta,
-                                      k_eval, tc.eval_episodes)
-            else:
-                with jax.named_scope("eval"):
-                    score = reward_fn(state.best_theta[None], k_eval)[0]
+            score, eval_key = evaluate(state.best_theta, eval_key)
         eval_pending.append((it, score))
         if len(eval_pending) >= METRIC_DRAIN_CHUNK or log is not None:
             # ``log`` wants the score now (interactive runs accept the

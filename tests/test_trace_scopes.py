@@ -97,6 +97,20 @@ def test_evaluate_best_carries_the_eval_scope():
     assert _scopes(low.compile()) == {"eval"}
 
 
+@pytest.mark.parametrize("task", ["pendulum", "landscape:sphere"])
+def test_jitted_evaluator_carries_the_eval_scope(task):
+    """The training loop's evaluation program (``make_evaluator``): the
+    key split and the score, episodes or landscape row, all under
+    ``eval``."""
+    from repro.envs import resolve_task
+    from repro.envs.rollout import make_evaluator
+    reward_fn, dim, init_fn, env, policy = resolve_task(task)
+    evaluate = make_evaluator(env, policy, 2, reward_fn)
+    theta = init_fn(jax.random.PRNGKey(0))
+    low = evaluate.lower(theta, jax.random.PRNGKey(1))
+    assert _scopes(low.compile()) == {"eval"}
+
+
 def test_scopes_change_no_numbers():
     """The scoped step against the same step traced with every
     ``jax.named_scope`` made a no-op: identical trajectories."""

@@ -1,5 +1,8 @@
 """TrainConfig construction contract + train_rl_netes eval-protocol
-bookkeeping (ISSUE 3 satellites)."""
+bookkeeping, and the evaluation program built once per run."""
+import json
+
+import jax
 import numpy as np
 import pytest
 
@@ -100,3 +103,54 @@ def test_scheduled_run_counts_match_static():
     assert len(h["reward_mean"]) == 10
     assert h["eval_iter"] == [3, 7, 9]
     assert np.isfinite(h["eval"]).all()
+
+
+# ---------------------------------------------------------------------------
+# the evaluation: one jitted program per run, the eager scores
+# ---------------------------------------------------------------------------
+
+def test_evaluation_compiles_once_and_matches_eager(tmp_path, monkeypatch):
+    """Pendulum, 3 evaluation points: only the first point's ``eval``
+    span compiles or loads a program (``xla_watch.Watch`` through the
+    JSONL trace), and each score equals, bit for bit, eager
+    ``evaluate_best`` on the best agent the loop evaluated and the key
+    it derives from ``PRNGKey(seed + 999)``."""
+    from repro.envs import resolve_task
+    from repro.envs.rollout import evaluate_best
+    from repro.train import loop
+
+    thetas = []
+    factory = loop.make_evaluator
+
+    def recording_factory(*args):
+        evaluate = factory(*args)
+
+        def call(theta, eval_key):
+            thetas.append(np.asarray(theta))
+            return evaluate(theta, eval_key)
+        return call
+
+    monkeypatch.setattr(loop, "make_evaluator", recording_factory)
+    seed, path = 5, tmp_path / "run.jsonl"
+    tc = TrainConfig(
+        n_agents=4, iters=6, eval_every=2, eval_episodes=2, seed=seed,
+        topology=TopologySpec(family="erdos_renyi", n_agents=4, p=0.5,
+                              seed=0),
+        trace=str(path))
+    h = train_rl_netes("pendulum", tc)
+    assert h["eval_iter"] == [1, 3, 5] and len(thetas) == 3
+
+    spans = [json.loads(line) for line in path.read_text().splitlines()]
+    evals = [r for r in spans if r.get("name") == "eval"]
+    assert len(evals) == 3
+    first, *later = evals
+    assert first["compiles"] + first["cache_loads"] >= 1
+    assert all(r["compiles"] == 0 and r["cache_loads"] == 0 for r in later)
+
+    _, _, _, env, policy = resolve_task("pendulum")
+    key = jax.random.PRNGKey(seed + 999)
+    eager = []
+    for theta in thetas:
+        key, k_eval = jax.random.split(key)
+        eager.append(float(evaluate_best(env, policy, theta, k_eval, 2)))
+    assert h["eval"] == eager
