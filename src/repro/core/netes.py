@@ -345,10 +345,10 @@ def run(state: NetESState, adj: jax.Array, reward_fn: Callable,
 
     With a ``mesh`` (DESIGN.md §13) the fleet runs agent-sharded via
     ``distributed.fleet_shard`` — same return shapes, halo/all-gather
-    collectives between shards. The sharded engine uses per-agent
-    fold-in RNG, so its trajectories form their own seed universe
-    (identical across mesh sizes, including mesh size 1, but not
-    bitwise-comparable to this module's single (N, D) draw).
+    collectives between shards. The sharded engine draws this
+    module's single (N, D) noise, each shard its own rows, so it equals
+    this path up to the order of its reductions (and, on the CPU, is
+    bitwise identical across mesh sizes, including mesh size 1).
 
     With ``probes`` (DESIGN.md §15) the ``MetricsState`` ring joins the
     scan carry and the return value grows by one element before the

@@ -31,21 +31,20 @@ edges into real collectives:
 Shard-invariance contract: for a fixed seed the trajectory (thetas,
 best_reward/theta, RNG carry) and the realized traffic counters are
 IDENTICAL for any mesh size, including 1, and identical to the solo
-(``mesh=None``) engine — the unsharded oracle. Two ingredients make
-that hold bitwise: per-agent fold-in RNG (an agent's ε depends on its
-global id, never on its placement), and contraction shapes pinned to N
-(row padding to ``n_pad = n_dev·ceil(N/n_dev)`` adds phantom zero-weight
-rows, but every reduction — fitness shaping, dense/full contractions,
-reward gathers — is sliced back to exactly N first). ``reward_fn`` must
-be row-decomposable (each row's return independent of the batch), which
-every env/landscape task satisfies.
+(``mesh=None``) engine. Two ingredients make that hold bitwise: the
+noise layout (agent g's ε is row g of ``normal(k_eps, (n_pad, D))``,
+drawn under ``jit`` with its rows sharded like θ, so each shard computes
+only its own rows; the threefry counters are partitionable, so row g
+does not depend on n_pad or on the placement), and contraction shapes
+pinned to N (row padding to ``n_pad = n_dev·ceil(N/n_dev)`` adds
+phantom zero-weight rows, but every reduction — fitness shaping,
+dense/full contractions, reward gathers — is sliced back to exactly N
+first). ``reward_fn`` must be row-decomposable (each row's return
+independent of the batch), which every env/landscape task satisfies.
 
-The engine's RNG layout (fold-in per agent) intentionally differs from
-``core.netes.netes_step``'s single (N, D) normal draw — that global
-draw cannot be sliced per shard without replaying the full threefry
-counter stream on every device. The solo engine IS the oracle the
-sharded runs are gated against; ``core.netes`` remains the
-single-device reference for everything else.
+The noise, the episode keys and the broadcast draws are those of
+``core.netes.netes_step``, so a sharded run equals the single-device
+engine up to the order of its reductions.
 """
 from __future__ import annotations
 
@@ -468,7 +467,8 @@ class ShardedNetES:
         if plan.mode == "replicated":
             topo = carry["ss"].topo if self.schedule is not None \
                 else self.topo
-            pert_full = ops.all_gather(pert_pos)[:n]
+            with jax.named_scope("exchange"):
+                pert_full = ops.all_gather(pert_pos)[:n]
             edge_mask = None
             wire = pert_full
             if chan is not None:
@@ -497,10 +497,11 @@ class ShardedNetES:
 
         if plan.mode == "halo":
             bufs = [list(parts)]
-            for r, _ in plan.rounds:
-                sidx = operands[f"send{r}"][0]
-                bufs.append([ops.ppermute_recv(
-                    jnp.take(p, sidx, axis=0), r) for p in parts])
+            with jax.named_scope("exchange"):
+                for r, _ in plan.rounds:
+                    sidx = operands[f"send{r}"][0]
+                    bufs.append([ops.ppermute_recv(
+                        jnp.take(p, sidx, axis=0), r) for p in parts])
             joined = tuple(
                 jnp.concatenate([b[i] for b in bufs], axis=0)
                 for i in range(len(parts)))
@@ -515,7 +516,8 @@ class ShardedNetES:
         # dense / full: all-gather the encoded payload, decode, contract
         # over EXACTLY n sources (contraction shapes pinned to N keeps
         # results identical across mesh sizes).
-        joined = tuple(ops.all_gather(p)[:n] for p in parts)
+        with jax.named_scope("exchange"):
+            joined = tuple(ops.all_gather(p)[:n] for p in parts)
         buf = decode(joined)
         if plan.mode == "dense":
             adjb = operands["adj_block"].astype(buf.dtype)
@@ -535,11 +537,13 @@ class ShardedNetES:
         deg = jnp.full((n_loc,), float(n), jnp.float32)
         return mixed, wsum, deg, cs, chan_metrics
 
-    def _step(self, ops, operands, carry):
+    def _step(self, ops, operands, carry, eps, k_eval, k_beta):
+        """One iteration on this shard's rows. ``eps`` is the shard's
+        slab of the iteration's (n_pad, D) noise; the key split that
+        gave ``k_eval`` and ``k_beta`` runs outside, with the draw."""
         plan, cfg, chan = self.plan, self.cfg, self.channel
         n, n_loc, n_pad = plan.n, plan.n_loc, plan.n_pad
         th = carry["th"]
-        d = th.shape[1]
         lo = ops.axis_index() * n_loc
         gid = lo + jnp.arange(n_loc, dtype=jnp.int32)
         valid = (gid < n).astype(th.dtype)
@@ -547,11 +551,6 @@ class ShardedNetES:
         # The parts carry core.netes.netes_step's named scopes (DESIGN.md
         # §15): op metadata only, no change to numerics or fusion.
         with jax.named_scope("noise"):
-            key, k_eps, k_eval, k_beta = jax.random.split(carry["key"], 4)
-            # placement-invariant per-agent noise (the netes_dist idiom):
-            # agent g's ε is a pure function of (k_eps, g).
-            eps = jax.vmap(lambda g: jax.random.normal(
-                jax.random.fold_in(k_eps, g), (d,), dtype=th.dtype))(gid)
             # Round σ·ε before the add: XLA is free to contract mul+add
             # chains into FMAs, and it decides per compiled program — the
             # (n_loc, D) and (N, D) programs can disagree in the last ulp.
@@ -567,23 +566,27 @@ class ShardedNetES:
             # (split(k, n_pad)[:N] == split(k, N)).
             rowwise = getattr(self.reward_fn, "rowwise", None)
             if rowwise is None:
-                return self.reward_fn(params, k_eval)
-            keys = jnp.take(jax.random.split(k_eval, n_pad), gid, axis=0)
-            return rowwise(params, keys)
+                r = self.reward_fn(params, k_eval)
+            else:
+                keys = jnp.take(jax.random.split(k_eval, n_pad), gid,
+                                axis=0)
+                r = rowwise(params, keys)
+            with jax.named_scope("exchange"):
+                return ops.all_gather(r)[:n]
 
         if cfg.antithetic:
             with jax.named_scope("noise"):
                 pert_neg = th - s_eps
             with jax.named_scope("reward"):
-                r_pos = ops.all_gather(rewards(pert_pos))[:n]
-                r_neg = ops.all_gather(rewards(pert_neg))[:n]
+                r_pos = rewards(pert_pos)
+                r_neg = rewards(pert_neg)
             with jax.named_scope("shaping"):
                 raw = jnp.concatenate([r_pos, r_neg])
                 shaped_all = netes.shape_fitness(raw, cfg.fitness_shaping)
                 shaped = shaped_all[:n] - shaped_all[n:]
         else:
             with jax.named_scope("reward"):
-                raw = ops.all_gather(rewards(pert_pos))[:n]
+                raw = rewards(pert_pos)
             with jax.named_scope("shaping"):
                 shaped = netes.shape_fitness(raw, cfg.fitness_shaping)
         with jax.named_scope("shaping"):
@@ -635,7 +638,7 @@ class ShardedNetES:
             better = iter_best_reward > carry["best_r"]
             out = dict(carry)
             out.update(
-                th=new_th, key=key, step=carry["step"] + 1,
+                th=new_th, step=carry["step"] + 1,
                 best_r=jnp.where(better, iter_best_reward, carry["best_r"]),
                 best_th=jnp.where(better, iter_best_theta,
                                   carry["best_th"]))
@@ -690,15 +693,46 @@ class ShardedNetES:
 
     # -- jitted run --------------------------------------------------------
     def _make_run_impl(self):
-        plan = self.plan
+        """The jitted run: one ``lax.scan`` whose body splits the key,
+        draws the iteration's (n_pad, D) noise with its rows placed like
+        θ's, then runs ``_step`` on each shard's rows (``shard_map``)."""
         have_chan = self.channel is not None
         have_sched = self.schedule is not None
         have_probes = self.probes is not None
 
-        def local_run(ops, th, key, step, best_r, best_th, operands, cs,
-                      ss, mst, num_iters):
-            carry = {"th": th, "key": key, "step": step, "best_r": best_r,
-                     "best_th": best_th}
+        if self.mesh is None:
+            def place(eps):
+                return eps
+
+            def step(carry, eps, k_eval, k_beta, operands):
+                return self._step(_SoloOps(), operands, carry, eps, k_eval,
+                                  k_beta)
+        else:
+            rows = P(self.axis, None)
+            sharding = NamedSharding(self.mesh, rows)
+            ops = _ShardOps(self.axis, self.plan.n_dev)
+            opspec = {k: self._operand_spec(k, v)
+                      for k, v in self.plan.operands.items()}
+
+            def place(eps):
+                # the draw's rows on the chips that hold them: each chip
+                # computes n_loc rows of the threefry stream
+                return jax.lax.with_sharding_constraint(eps, sharding)
+
+            def step(carry, eps, k_eval, k_beta, operands):
+                specs = jax.tree.map(lambda _: P(), carry)
+                specs["th"] = rows
+                return jax.shard_map(
+                    lambda c, e, ke, kb, o: self._step(ops, o, c, e, ke, kb),
+                    mesh=self.mesh,
+                    in_specs=(specs, rows, P(), P(), opspec),
+                    out_specs=(specs, P()),
+                    check_vma=False)(carry, eps, k_eval, k_beta, operands)
+
+        def run_impl(th, key, step_count, best_r, best_th, operands, cs, ss,
+                     mst, num_iters):
+            carry = {"th": th, "key": key, "step": step_count,
+                     "best_r": best_r, "best_th": best_th}
             if have_chan:
                 carry["cs"] = cs[0]
             if have_sched:
@@ -707,7 +741,15 @@ class ShardedNetES:
                 carry["ms"] = mst[0]
 
             def body(c, _):
-                return self._step(ops, operands, c)
+                c = dict(c)
+                th = c["th"]
+                with jax.named_scope("noise"):
+                    key, k_eps, k_eval, k_beta = jax.random.split(
+                        c.pop("key"), 4)
+                    eps = place(jax.random.normal(k_eps, th.shape,
+                                                  dtype=th.dtype))
+                c, m = step(c, eps, k_eval, k_beta, operands)
+                return dict(c, key=key), m
 
             carry, ms = jax.lax.scan(body, carry, None, length=num_iters)
             cs_out = (carry["cs"],) if have_chan else ()
@@ -716,32 +758,6 @@ class ShardedNetES:
             return (carry["th"], carry["key"], carry["step"],
                     carry["best_r"], carry["best_th"], cs_out, ss_out,
                     mst_out, ms)
-
-        if self.mesh is None:
-            def run_impl(th, key, step, best_r, best_th, operands, cs, ss,
-                         mst, num_iters):
-                return local_run(_SoloOps(), th, key, step, best_r,
-                                 best_th, operands, cs, ss, mst, num_iters)
-            return run_impl
-
-        axis = self.axis
-        ops = _ShardOps(axis, plan.n_dev)
-        opspec = {k: self._operand_spec(k, v)
-                  for k, v in plan.operands.items()}
-
-        def run_impl(th, key, step, best_r, best_th, operands, cs, ss,
-                     mst, num_iters):
-            repl = lambda tree: jax.tree.map(lambda _: P(), tree)
-            fn = jax.shard_map(
-                lambda *a: local_run(ops, *a, num_iters),
-                mesh=self.mesh,
-                in_specs=(P(axis, None), P(), P(), P(), P(), opspec,
-                          repl(cs), repl(ss), repl(mst)),
-                out_specs=(P(axis, None), P(), P(), P(), P(), repl(cs),
-                           repl(ss), repl(mst), P()),
-                check_vma=False)
-            return fn(th, key, step, best_r, best_th, operands, cs, ss,
-                      mst)
 
         return run_impl
 
@@ -762,10 +778,16 @@ class ShardedNetES:
         cs = (chan_state,) if self.channel is not None else ()
         ss = (sched_state,) if self.schedule is not None else ()
         mst = (metrics_state,) if self.probes is not None else ()
+        args = (state.key, state.step, state.best_reward, state.best_theta)
+        if self.mesh is not None:
+            # one placement for every call, the first chunk's included:
+            # the compiled run is then looked up, never built again
+            th = jax.device_put(th, NamedSharding(self.mesh,
+                                                  P(self.axis, None)))
+            args, cs, ss, mst = jax.device_put(
+                (args, cs, ss, mst), NamedSharding(self.mesh, P()))
         (th, key, step, best_r, best_th, cs_out, ss_out, mst_out,
-         metrics) = self._run_impl(th, state.key, state.step,
-                                   state.best_reward, state.best_theta,
-                                   self._operands, cs, ss, mst,
+         metrics) = self._run_impl(th, *args, self._operands, cs, ss, mst,
                                    num_iters=num_iters)
         if plan.n_pad != n:
             th = th[:n]
@@ -820,8 +842,9 @@ def clear_engine_cache():
     _ENGINE_CACHE.clear()
 
 
-def _get_engine(topo, reward_fn, cfg, mesh, channel, schedule,
-                probes=None):
+def get_engine(topo, reward_fn, cfg, mesh, channel, schedule,
+               probes=None):
+    """The engine for these arguments, built once and then cached."""
     key = (id(topo), id(schedule), reward_fn, cfg, channel, mesh, probes)
     eng = _ENGINE_CACHE.get(key)
     if eng is None or eng.topo is not topo or eng.schedule is not schedule:
@@ -841,8 +864,8 @@ def run_sharded(state: NetESState, adj, reward_fn: Callable,
     or ``FullyConnected`` instance for engine caching."""
     topo = adj if isinstance(adj, (Topology, FullyConnected)) \
         else topology_repr.as_topology(adj)
-    eng = _get_engine(topo, reward_fn, cfg, mesh, channel, None,
-                      probes=probes)
+    eng = get_engine(topo, reward_fn, cfg, mesh, channel, None,
+                     probes=probes)
     return eng.run(state, num_iters, chan_state=chan_state,
                    metrics_state=metrics_state)
 
@@ -855,8 +878,8 @@ def run_sharded_scheduled(state: NetESState, sched_state,
     """``core.netes.run_scheduled``'s ``mesh=`` backend (replicated
     mixing — schedules mutate the graph on device, so every shard keeps
     the full topology state; honest accounting: FC-level bytes)."""
-    eng = _get_engine(None, reward_fn, cfg, mesh, channel, schedule,
-                      probes=probes)
+    eng = get_engine(None, reward_fn, cfg, mesh, channel, schedule,
+                     probes=probes)
     return eng.run(state, num_iters, chan_state=chan_state,
                    sched_state=sched_state, metrics_state=metrics_state)
 

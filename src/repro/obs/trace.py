@@ -102,19 +102,21 @@ class Trace:
         self._fh.flush()
 
     @contextlib.contextmanager
-    def span(self, name: str, **attrs: Any) -> Iterator[None]:
+    def span(self, name: str, **attrs: Any) -> Iterator[Dict[str, Any]]:
         """Wall-time span stamped with the XLA compiles, host transfers
         and cache loads that happened inside it, and the profiler
-        annotation ``repro/<name>`` around it."""
+        annotation ``repro/<name>`` around it. Yields the span's
+        attribute dict: what the body adds to it is written with the
+        span."""
         with jax.profiler.TraceAnnotation(f"repro/{name}"):
             if self._fh is None:
-                yield
+                yield attrs
                 return
             c0, x0, l0 = self._watch.snapshot()
             t0 = time.time()
             self._depth += 1
             try:
-                yield
+                yield attrs
             finally:
                 self._depth -= 1
                 c1, x1, l1 = self._watch.snapshot()

@@ -17,6 +17,7 @@ from typing import Callable, Dict, List, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import checkpoint
 from repro.comm import channel as comm_channel
@@ -67,8 +68,9 @@ class TrainConfig:
     # Shard the agent axis over this many devices (DESIGN.md §13): the
     # fused scans route through distributed.fleet_shard with halo /
     # all-gather collectives between shards. None ⇒ single-device path.
-    # Trajectories are identical for ANY shard count (1 included) but
-    # form their own RNG universe vs the unsharded engine.
+    # The noise layout is the single-device engine's, so a sharded run
+    # equals it up to reduction order; on the CPU, trajectories are
+    # bitwise identical for ANY shard count (1 included).
     shards: Optional[int] = None
     seed: int = 0
     eval_every: int = 0             # 0 ⇒ paper protocol (prob 0.08)
@@ -194,7 +196,7 @@ def train_rl_netes(task: str, tc: TrainConfig,
     tr = Trace(tc.trace, name=f"rl:{task}", task=task,
                n_agents=tc.n_agents, iters=tc.iters,
                probes=tc.probes.label() if tc.probes is not None else None)
-    with tr.span("build"):
+    with tr.span("build") as build_attrs:
         key = jax.random.PRNGKey(tc.seed)
         reward_fn, dim, init_fn, env, policy = resolve_task(task)
 
@@ -213,6 +215,12 @@ def train_rl_netes(task: str, tc: TrainConfig,
         probes = build_probes(tc, channel=channel, dim=dim)
         mstate = probes.init() if probes is not None else None
         evaluate = make_evaluator(env, policy, tc.eval_episodes, reward_fn)
+        if mesh is not None:
+            # per-shard bytes each iteration's collectives move, from the
+            # engine the first chunk then runs
+            build_attrs.update(fleet_shard.get_engine(
+                topo, reward_fn, tc.netes, mesh, channel, schedule,
+                probes).collective_bytes(dim))
     history: Dict[str, List] = {"reward_mean": [], "reward_max": [],
                                 "eval": [], "eval_iter": []}
     if channel is not None:
@@ -272,6 +280,11 @@ def train_rl_netes(task: str, tc: TrainConfig,
         sstate = restored.get("sched", sstate)
         cstate = restored.get("chan", cstate)
         mstate = restored.get("obs", mstate)
+    if mesh is not None:
+        # the evaluation reads the replicated best row; its key lives on
+        # the same devices from the first point on, or the second point
+        # would build the evaluator again for the key's new placement
+        eval_key = jax.device_put(eval_key, NamedSharding(mesh, P()))
 
     def _unpack(out):
         """Every step/run entry point returns ``(state[, sstate]

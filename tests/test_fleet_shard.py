@@ -32,14 +32,13 @@ def _sparse_topo(n=N, p=0.3, seed=2):
 
 
 def _expected_one_step(topo, state, cfg):
-    """Pure-numpy Eq. 3 oracle using the engine's per-agent fold-in RNG
-    (p_broadcast=0 keeps the broadcast overwrite out of the picture)."""
+    """Pure-numpy Eq. 3 oracle with ``core.netes``'s noise layout: ε is
+    one ``normal(k_eps, (N, D))`` (p_broadcast=0 keeps the broadcast
+    overwrite out of the picture)."""
     th = np.asarray(state.thetas)
     n, d = th.shape
     _, k_eps, k_eval, _ = jax.random.split(state.key, 4)
-    gid = jnp.arange(n, dtype=jnp.int32)
-    eps = np.asarray(jax.vmap(lambda g: jax.random.normal(
-        jax.random.fold_in(k_eps, g), (d,), dtype=jnp.float32))(gid))
+    eps = np.asarray(jax.random.normal(k_eps, (n, d), dtype=jnp.float32))
     pert_pos = th + cfg.sigma * eps
     pert_neg = th - cfg.sigma * eps
     r_pos = np.asarray(_reward(jnp.asarray(pert_pos), k_eval))
@@ -132,6 +131,40 @@ def test_train_loop_shards_smoke():
         netes=NetESConfig(alpha=0.05, sigma=0.1, p_broadcast=0.5))
     h = train_rl_netes("landscape:sphere", tc)
     assert len(h["reward_mean"]) == 4
+
+
+def test_build_span_records_the_collective_bytes(tmp_path):
+    """With ``shards`` set, the JSONL trace's ``build`` span carries the
+    engine's per-shard collective bytes; without it, none."""
+    from repro.core.topology import TopologySpec
+    from repro.envs import LANDSCAPE_DIM
+    from repro.obs.trace import read_trace
+    from repro.train.loop import (TrainConfig, build_channel,
+                                  build_topology, train_rl_netes)
+
+    def build_attrs(shards, name):
+        tc = TrainConfig(
+            n_agents=8, iters=2, topology=TopologySpec(
+                family="erdos_renyi", n_agents=8, p=0.4, seed=0),
+            representation="sparse", channel="quantize(bits=8)",
+            shards=shards, seed=0, eval_every=2, eval_episodes=1,
+            trace=str(tmp_path / name),
+            netes=NetESConfig(alpha=0.05, sigma=0.1, p_broadcast=0.5))
+        train_rl_netes("landscape:sphere", tc)
+        spans = [r for r in read_trace(tmp_path / name)
+                 if r["kind"] == "span" and r["name"] == "build"]
+        assert len(spans) == 1
+        return tc, spans[0].get("attrs", {})
+
+    tc, attrs = build_attrs(1, "sharded.jsonl")
+    want = fleet_shard.ShardedNetES(
+        build_topology(tc), _reward, tc.netes,
+        channel=build_channel(tc)).collective_bytes(LANDSCAPE_DIM)
+    assert attrs == want
+    assert attrs["total_bytes"] == (attrs["payload_bytes"]
+                                    + attrs["reward_bytes"]
+                                    + attrs["broadcast_bytes"])
+    assert build_attrs(None, "solo.jsonl")[1] == {}
 
 
 def test_env_reward_rowwise_keys_follow_the_agent():
@@ -304,3 +337,169 @@ def test_shard_invariance_on_8_forced_devices():
                 if k not in ("XLA_FLAGS",)}})
     assert "FLEET_SHARD_MESH_OK" in res.stdout, \
         (res.stdout[-2000:], res.stderr[-4000:])
+
+
+# ---------------------------------------------------------------------------
+# the sharded engine against the single-device engine, and its noise
+# draw per shard (4 forced host devices, one subprocess)
+# ---------------------------------------------------------------------------
+
+_FOUR_DEVICE_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import json
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.comm import channel as comm_channel
+from repro.core import netes, topology, topology_repr
+from repro.core.netes import NetESConfig
+from repro.core.topology import TopologySpec
+from repro.distributed import fleet_shard
+from repro.train.loop import TrainConfig, train_rl_netes
+
+out = {}
+
+# train_rl_netes(shards=4) against shards=None (core.netes): the first
+# chunk of 3 iterations, read where the loop calls netes.run
+N = 64
+LEGS = (("landscape:sphere", "quantize(bits=8)"), ("landscape:sphere", None),
+        ("pendulum", "quantize(bits=8)"))
+first = {}
+orig = netes.run
+
+
+def run(*args, **kwargs):
+    res = orig(*args, **kwargs)
+    first.setdefault(kwargs.get("mesh") is not None, res)
+    return res
+
+
+netes.run = run
+for task, chan in LEGS:
+    first.clear()
+    for shards in (None, 4):
+        tc = TrainConfig(
+            n_agents=N, iters=3, topology=TopologySpec(
+                family="erdos_renyi", n_agents=N, p=0.1, seed=0),
+            representation="sparse", channel=chan, shards=shards, seed=5,
+            eval_every=3, eval_episodes=1,
+            netes=NetESConfig(alpha=0.05, sigma=0.1, p_broadcast=0.8))
+        train_rl_netes(task, tc)
+    leg = {}
+    for sharded, res in first.items():
+        m = jax.device_get(res[-1])
+        leg[str(sharded)] = {
+            "reward_mean": np.asarray(m["reward_mean"]).tolist(),
+            "update_var": np.asarray(m["update_var"]).tolist(),
+            "broadcast": np.asarray(m["broadcast"]).tolist(),
+            "thetas": np.asarray(jax.device_get(res[0].thetas)).tolist()}
+    out[f"{task} {chan}"] = leg
+netes.run = orig
+
+# after the first evaluation point nothing is built again: the chunks
+# and the evaluations keep their placements from then on
+compiles = []
+seen = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, duration, **kw: compiles.append(len(seen))
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+tc = TrainConfig(
+    n_agents=N, iters=12, topology=TopologySpec(
+        family="erdos_renyi", n_agents=N, p=0.1, seed=0),
+    representation="sparse", channel="quantize(bits=8)", shards=4, seed=6,
+    eval_every=3, eval_episodes=1, netes=NetESConfig())
+train_rl_netes("pendulum", tc, log=lambda entry: seen.append(entry))
+out["compiles_after_first_eval"] = sum(1 for k in compiles if k > 0)
+out["eval_points"] = len(seen)
+
+# the compiled sharded run: the unsigned words of the threefry stream
+# come in (n_loc, D) slabs, never at the population's (n_pad, D)
+n, d = 64, 24
+topo = topology_repr.from_dense(topology.erdos_renyi(n, p=0.1, seed=0),
+                                "sparse")
+eng = fleet_shard.ShardedNetES(
+    topo, lambda p, k: -(p * p).sum(axis=-1), NetESConfig(),
+    mesh=fleet_shard.build_mesh(4),
+    channel=comm_channel.compile_channel("quantize(bits=8)", n))
+state = netes.init_state(jax.random.PRNGKey(0), n, d)
+cs = eng.channel.init(state.thetas)
+text = eng._run_impl.lower(
+    state.thetas, state.key, state.step, state.best_reward,
+    state.best_theta, eng._operands, (cs,), (), (),
+    num_iters=2).compile().as_text()
+out["u32_shapes"] = sorted(set(re.findall(r"u32\[(\d+),(\d+)\]", text)))
+out["n_loc"] = eng.plan.n_loc
+print("FOUR_DEVICES " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def four_devices():
+    res = subprocess.run(
+        [sys.executable, "-c", _FOUR_DEVICE_SCRIPT],
+        capture_output=True, text=True, timeout=600,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             **{k: v for k, v in __import__("os").environ.items()
+                if k not in ("XLA_FLAGS",)}})
+    lines = [ln for ln in res.stdout.splitlines()
+             if ln.startswith("FOUR_DEVICES ")]
+    assert lines, (res.stdout[-2000:], res.stderr[-4000:])
+    import json
+    return json.loads(lines[-1][len("FOUR_DEVICES "):])
+
+
+@pytest.mark.parametrize("channel", ["quantize(bits=8)", None])
+def test_sharded_training_matches_the_single_device_engine(four_devices,
+                                                           channel):
+    """``train_rl_netes(shards=4)`` draws the single-device engine's
+    noise and broadcasts: after 3 iterations on a smooth landscape the
+    mean return, the update variance and θ equal ``shards=None`` to
+    float32 rounding, over the int8 channel (as the x4 cell runs) and
+    without one."""
+    leg = four_devices[f"landscape:sphere {channel}"]
+    solo, sharded = leg["False"], leg["True"]
+    assert sharded["broadcast"] == solo["broadcast"]
+    for k in ("reward_mean", "update_var", "thetas"):
+        np.testing.assert_allclose(np.asarray(sharded[k]),
+                                   np.asarray(solo[k]), rtol=5e-6,
+                                   atol=1e-7, err_msg=k)
+
+
+def test_sharded_pendulum_episodes_match_the_single_device_engine(
+        four_devices):
+    """The pendulum over the int8 channel (the x4 cell's task): the
+    first iteration's mean return and update variance equal
+    ``shards=None`` to float32 rounding, and so do the broadcast draws
+    of the chunk. Later iterations are not compared: an episode's
+    return jumps with the last bit of the policy (perfbench/compare.py),
+    so one ulp of a reduction order may move the second iteration."""
+    leg = four_devices["pendulum quantize(bits=8)"]
+    solo, sharded = leg["False"], leg["True"]
+    assert sharded["broadcast"] == solo["broadcast"]
+    for k in ("reward_mean", "update_var"):
+        np.testing.assert_allclose(sharded[k][0], solo[k][0], rtol=5e-6,
+                                   err_msg=k)
+
+
+def test_sharded_training_compiles_nothing_after_its_first_eval(
+        four_devices):
+    """Chunks 2-4 and their evaluations reuse the programs the first
+    chunk and evaluation built: the placements stay put."""
+    assert four_devices["eval_points"] == 4
+    assert four_devices["compiles_after_first_eval"] == 0
+
+
+def test_each_shard_draws_only_its_own_noise_rows(four_devices):
+    """The compiled 4-shard run generates the threefry words of its
+    (n_pad, D) = (64, 24) noise as (16, 24) slabs, one per device, and
+    never the whole draw."""
+    shapes = {tuple(int(x) for x in s) for s in four_devices["u32_shapes"]}
+    n_loc = four_devices["n_loc"]
+    assert n_loc == 16
+    assert (n_loc, 24) in shapes
+    assert (64, 24) not in shapes, shapes
