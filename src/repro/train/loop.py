@@ -215,6 +215,16 @@ def train_rl_netes(task: str, tc: TrainConfig,
         probes = build_probes(tc, channel=channel, dim=dim)
         mstate = probes.init() if probes is not None else None
         evaluate = make_evaluator(env, policy, tc.eval_episodes, reward_fn)
+        blocks = getattr(reward_fn, "blocks", None)
+        if blocks is not None:
+            # the agent blocks of one reward call: over all N rows, or
+            # over one shard's rows on a mesh
+            rows = tc.n_agents
+            if mesh is not None:
+                rows = -(-rows // mesh.size)
+            n_blocks, block_rows = blocks(rows)
+            build_attrs.update(reward_blocks=n_blocks,
+                               reward_block_rows=block_rows)
         if mesh is not None:
             # per-shard bytes each iteration's collectives move, from the
             # engine the first chunk then runs

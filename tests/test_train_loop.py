@@ -154,3 +154,23 @@ def test_evaluation_compiles_once_and_matches_eager(tmp_path, monkeypatch):
         key, k_eval = jax.random.split(key)
         eager.append(float(evaluate_best(env, policy, theta, k_eval, 2)))
     assert h["eval"] == eager
+
+
+def test_build_span_records_the_reward_blocks(tmp_path):
+    """A pendulum run's JSONL ``build`` span carries the agent blocks its
+    reward runs in: one block of all N rows at a small N."""
+    from repro.obs.trace import read_trace
+
+    path = tmp_path / "run.jsonl"
+    n = 6
+    tc = TrainConfig(
+        n_agents=n, iters=2, eval_every=2, eval_episodes=1, seed=0,
+        topology=TopologySpec(family="erdos_renyi", n_agents=n, p=0.5,
+                              seed=0),
+        trace=str(path))
+    train_rl_netes("pendulum", tc)
+    builds = [r for r in read_trace(path)
+              if r["kind"] == "span" and r["name"] == "build"]
+    assert len(builds) == 1
+    attrs = builds[0]["attrs"]
+    assert (attrs["reward_blocks"], attrs["reward_block_rows"]) == (1, n)
