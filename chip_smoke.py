@@ -41,12 +41,10 @@ import pathlib
 import sys
 import time
 
-# Run from a checkout without PYTHONPATH: the repo root provides
-# ``benchmarks``, ``src`` provides ``repro`` (as in benchmarks/run.py).
-_ROOT = pathlib.Path(__file__).resolve().parent
-for _p in (str(_ROOT), str(_ROOT / "src")):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+# Run from a checkout without PYTHONPATH: ``src`` provides ``repro``.
+_SRC = str(pathlib.Path(__file__).resolve().parent / "src")
+if _SRC not in sys.path:
+    sys.path.insert(0, _SRC)
 
 TASK = "pendulum"
 PENDULUM_DIM = 4481

@@ -2,22 +2,23 @@
 (1) same init, no broadcast; (2) same init + broadcast;
 (3) different init + broadcast; (4) different init, no broadcast —
 vs NetES on an Erdos-Renyi graph. Shows the gain comes from topology.
-(Paper: MuJoCo Ant, 100 agents. Here: pendulum.)
+(Paper: MuJoCo Ant, 100 agents. Here: cartpole swing-up.)
+
+  PYTHONPATH=src python examples/paper/fig3b_controls.py [--quick]
 """
 from __future__ import annotations
 
-import time
+import argparse
 
 import jax
 import numpy as np
+from common import save_result
 
 from repro.core import netes
 from repro.core.netes import NetESConfig
 from repro.envs import ENVS, MLPPolicy, make_env_reward_fn
 from repro.envs.rollout import evaluate_best
 from repro.train.loop import TrainConfig, build_adjacency
-
-from . import common, registry
 
 CONTROLS = [
     ("fc_same_init_no_bcast", "fully_connected", True, 0.0),
@@ -47,7 +48,6 @@ def _run_control(task, family, same_init, p_b, n, iters, seed):
 def run(quick: bool = False):
     n, iters, seeds = (16, 20, range(2)) if quick else (40, 60, range(2))
     task = "cartpole_swingup"
-    t0 = time.time()
     rows = {}
     for name, fam, same, p_b in CONTROLS:
         scores = [_run_control(task, fam, same, p_b, n, iters, s)
@@ -58,21 +58,15 @@ def run(quick: bool = False):
                                     / np.sqrt(len(arr)))
                       if len(arr) > 1 else 0.0,
                       "scores": scores}
-    best_control = max((v["mean"] for k, v in rows.items()
-                        if k != "netes_erdos"))
-    rows["wall_s"] = time.time() - t0
-    common.emit("fig3b.controls", rows["wall_s"],
-                f"netes_er={rows['netes_erdos']['mean']:.2f} "
-                f"best_fc_control={best_control:.2f}")
-    common.save_result("fig3b_controls", rows)
+    best_control = max(v["mean"] for k, v in rows.items()
+                       if k != "netes_erdos")
+    print(f"netes_er={rows['netes_erdos']['mean']:.2f} "
+          f"best_fc_control={best_control:.2f}")
+    save_result("fig3b_controls", rows)
     return rows
 
 
-@registry.register("fig3b", group="topologies", profiles=("quick", "full"))
-def bench(ctx: registry.Context):
-    rows = run(quick=ctx.quick)
-    return [registry.Entry(
-        name="fig3b.controls",
-        wall_s=rows["wall_s"],
-        eval_score=rows["netes_erdos"]["mean"],
-        extra={k: v["mean"] for k, v in rows.items() if k != "wall_s"})]
+if __name__ == "__main__":
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--quick", action="store_true", help="smoke scale")
+    run(quick=ap.parse_args().quick)

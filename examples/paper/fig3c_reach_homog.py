@@ -1,23 +1,23 @@
 """Paper Fig 3C: reachability/homogeneity scatter over random instances of
 the four families — Erdos-Renyi maximizes reachability & minimizes
 homogeneity; fully-connected is the extreme opposite.
+
+  PYTHONPATH=src python examples/paper/fig3c_reach_homog.py [--quick]
 """
 from __future__ import annotations
 
-import time
+import argparse
 
 import numpy as np
+from common import save_result
 
 from repro.core import topology
-
-from . import common, registry
 
 FAMILIES = ["erdos_renyi", "scale_free", "small_world", "fully_connected"]
 
 
 def run(quick: bool = False):
     n, n_seeds = (100, 5) if quick else (300, 15)
-    t0 = time.time()
     rows = {}
     for fam in FAMILIES:
         pts = []
@@ -33,25 +33,13 @@ def run(quick: bool = False):
     er, fc = rows["erdos_renyi"], rows["fully_connected"]
     ok = (er["reachability_mean"] > fc["reachability_mean"]
           and er["homogeneity_mean"] < fc["homogeneity_mean"])
-    wall_s = time.time() - t0
-    common.emit("fig3c.reach_homog", wall_s,
-                f"er_extremizes={ok} er_reach={er['reachability_mean']:.4f} "
-                f"fc_reach={fc['reachability_mean']:.4f}")
-    common.save_result("fig3c_reach_homog", rows)
-    rows["wall_s"] = wall_s
+    print(f"er_extremizes={ok} er_reach={er['reachability_mean']:.4f} "
+          f"fc_reach={fc['reachability_mean']:.4f}")
+    save_result("fig3c_reach_homog", rows)
     return rows
 
 
-@registry.register("fig3c", group="topologies")
-def bench(ctx: registry.Context):
-    rows = run(quick=ctx.quick)
-    er, fc = rows["erdos_renyi"], rows["fully_connected"]
-    # eval_score: the ER reachability advantage over FC — deterministic
-    # given the seeds, higher is better, the figure's headline claim.
-    return [registry.Entry(
-        name="fig3c.reach_homog",
-        wall_s=rows["wall_s"],
-        eval_score=er["reachability_mean"] - fc["reachability_mean"],
-        extra={fam: {"reachability_mean": rows[fam]["reachability_mean"],
-                     "homogeneity_mean": rows[fam]["homogeneity_mean"]}
-               for fam in FAMILIES})]
+if __name__ == "__main__":
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--quick", action="store_true", help="smoke scale")
+    run(quick=ap.parse_args().quick)

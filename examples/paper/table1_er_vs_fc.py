@@ -2,12 +2,14 @@
 tasks (paper: 1000 agents on Mujoco Ant/HalfCheetah/Hopper/Humanoid +
 Roboschool Humanoid). Here: five reduced tasks spanning the same kinds of
 difficulty — three JAX control tasks + two rugged landscapes.
+
+  PYTHONPATH=src python examples/paper/table1_er_vs_fc.py [--quick]
 """
 from __future__ import annotations
 
-import time
+import argparse
 
-from . import common, registry
+from common import compare, save_result
 
 TASKS = ["pendulum", "cartpole_swingup", "acrobot",
          "landscape:rastrigin@2.5", "landscape:ackley@2.5"]
@@ -18,9 +20,8 @@ def run(quick: bool = False):
     tasks = TASKS[:2] + TASKS[3:4] if quick else TASKS
     rows = {}
     for task in tasks:
-        t0 = time.time()
-        res = common.compare(task, ["fully_connected", "erdos_renyi"],
-                             n, iters, seeds)
+        res = compare(task, ["fully_connected", "erdos_renyi"], n, iters,
+                      seeds)
         er, fc = res["erdos_renyi"]["mean"], res["fully_connected"]["mean"]
         # paper reports % improvement of ER over FC
         denom = abs(fc) if abs(fc) > 1e-9 else 1.0
@@ -28,22 +29,13 @@ def run(quick: bool = False):
         rows[task] = {"fully_connected": fc, "erdos_renyi": er,
                       "improvement_pct": improv,
                       "fc_ci": res["fully_connected"]["ci95"],
-                      "er_ci": res["erdos_renyi"]["ci95"],
-                      "wall_s": time.time() - t0}
-        common.emit(f"table1.{task.replace(':', '_')}",
-                    rows[task]["wall_s"],
-                    f"fc={fc:.2f} er={er:.2f} improv={improv:+.1f}%")
-    common.save_result("table1_er_vs_fc", rows)
+                      "er_ci": res["erdos_renyi"]["ci95"]}
+        print(f"{task}: fc={fc:.2f} er={er:.2f} improv={improv:+.1f}%")
+    save_result("table1_er_vs_fc", rows)
     return rows
 
 
-@registry.register("table1", group="topologies", profiles=("quick", "full"))
-def bench(ctx: registry.Context):
-    rows = run(quick=ctx.quick)
-    return [registry.Entry(
-        name=f"table1.{task.replace(':', '_')}",
-        wall_s=r["wall_s"],
-        eval_score=r["erdos_renyi"],
-        extra={"fully_connected": r["fully_connected"],
-               "improvement_pct": r["improvement_pct"]})
-        for task, r in rows.items()]
+if __name__ == "__main__":
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--quick", action="store_true", help="smoke scale")
+    run(quick=ap.parse_args().quick)

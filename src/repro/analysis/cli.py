@@ -22,7 +22,7 @@ from typing import List, Optional
 from .findings import Finding
 
 REPO_ROOT = Path(__file__).resolve().parents[3]
-DEFAULT_PATHS = ("src/repro", "benchmarks", "examples")
+DEFAULT_PATHS = ("src/repro", "examples")
 
 
 def _default_paths() -> List[Path]:

@@ -42,14 +42,13 @@ of ``core/topology_sched.py``:
     happens ON DEVICE with zero steady-state recompiles (gated by
     ``count_backend_compiles`` exactly like schedules are).
 
-Realized vs modeled traffic: ``benchmarks/perfmodel.wire_bytes`` models
-the topology's *capacity*; the channel counts what actually moved —
+Realized traffic: the channel counts what actually moved —
 per-step live directed edges × triggered sources (plus broadcast
 events), accumulated in ``ChannelState.msgs`` and emitted per step in
 the metrics. ``payload_bytes`` converts message counts to wire bytes
 under the pipeline's encoding (bits/element × kept fraction + top-k
-index overhead). The resilience bench gates the realized counter the
-same way modeled wire bytes are gated (exact equality).
+index overhead). tests/test_channel.py holds the realized counter of a
+topology × channel grid to the count of live edges (exact equality).
 """
 from __future__ import annotations
 
@@ -197,8 +196,8 @@ class Channel:
     contraction the encoded ``WirePayload`` (``apply_wire``) instead of
     the fake-quant f32 payload, and ``topology_repr`` routes sparse
     graphs through ``kernels/netes_fused_mixing``. False forces the
-    legacy decode-then-contract path — the benches' unfused control
-    legs. Either way the channel's *semantics* (scale, rounding, masks,
+    legacy decode-then-contract path — the tests' unfused control
+    runs. Either way the channel's *semantics* (scale, rounding, masks,
     traffic accounting) are identical."""
 
     spec: ChannelSpec
@@ -427,7 +426,7 @@ def compile_channel(spec: Optional[ChannelSpec | str], n: int,
                     fused: bool = True) -> Channel:
     """Resolve a ``ChannelSpec`` (or its string form; None compiles as
     lossless) for an n-agent population. ``fused=False`` pins the legacy
-    fake-quant dispatch (the benches' unfused control legs)."""
+    fake-quant dispatch (the tests' unfused control runs)."""
     if spec is None:
         spec = ChannelSpec()
     elif isinstance(spec, str):

@@ -76,7 +76,7 @@ def init_state(key: jax.Array, n_agents: int, dim: int,
         # explicit dtype: a weak-typed scalar here would come back
         # strong-typed from the first fused scan, giving the second
         # same-shape chunk a NEW jit signature (one spurious recompile
-        # mid-run — caught by the fleet bench's compile-count gate)
+        # mid-run — caught by tests/test_train_loop.py's warm-rerun gate)
         best_reward=jnp.full((), -jnp.inf, jnp.float32),
         best_theta=thetas[0],
     )
@@ -105,8 +105,10 @@ def mixing_update(adj, thetas: jax.Array, perturbed: jax.Array,
     the contraction runs O(N²·D) dense, O(N·K·D) neighbor-gather, or
     O(N·|Δ|·D) roll-chain depending on ``topo.kind`` (DESIGN.md §3). All
     three paths are parity-tested against each other in
-    tests/test_topology_repr.py. The dense hot loop is fused by
-    kernels/netes_mixing; the sparse one by kernels/netes_sparse_mixing.
+    tests/test_topology_repr.py, and each against the jnp oracles of
+    ``kernels/ref.py``. All three are XLA code (``topology_repr.
+    weighted_neighbor_sum``); only a quantized wire payload reaches a
+    Pallas kernel (``kernels/netes_fused_mixing``).
 
     ``edge_mask`` (DESIGN.md §11): a representation-matched live-link
     mask from a lossy channel — a dropped link removes source i's term

@@ -57,10 +57,12 @@ from . import wire_format
 Array = jax.Array
 
 # Density at or below which the neighbor-list representation is preferred
-# over dense. The flop ratio is N/K ≈ 1/(2p−p²); the measured CPU crossover
-# (benchmarks/kernel_bench.py sparse_crossover) and the distributed
-# communication model both favor sparse well below this cutoff, while at
-# p ≳ 0.3 the padded K_max approaches N and sparse is strictly worse.
+# over dense. The flop ratio is N/K ≈ 1/(2p−p²); a CPU crossover sweep (a
+# since-deleted microbenchmark, run on CPU Eigen against XLA row gathers)
+# and the distributed communication model both favored sparse well below
+# this cutoff, while at p ≳ 0.3 the padded K_max approaches N and sparse is
+# strictly worse. Nothing has measured the crossover on a TPU yet (ROADMAP
+# queue 1, item 2).
 SPARSE_DENSITY_CUTOFF = 0.25
 
 # A circulant offset chain costs one ppermute per signed offset; past this

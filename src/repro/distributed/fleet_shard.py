@@ -70,7 +70,7 @@ def build_mesh(num_shards: Optional[int] = None, axis: str = AXIS) -> Mesh:
     """1-D mesh over the first ``num_shards`` local devices (all, if
     None). Simulated multi-device CPU runs set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` BEFORE
-    importing jax (see benchmarks/README.md)."""
+    importing jax (as tests/test_fleet_shard.py's subprocesses do)."""
     devs = jax.devices()
     n = len(devs) if num_shards is None else int(num_shards)
     if not 1 <= n <= len(devs):
@@ -381,8 +381,8 @@ class ShardedNetES:
 
     Build once per (topology × config × mesh × channel/schedule) and call
     :meth:`run` repeatedly — the jitted program is cached per
-    ``num_iters``, so steady-state replays compile nothing (gated by the
-    fleet16k bench). ``topo`` may be a ``Topology``, a
+    ``num_iters``, so steady-state replays compile nothing (gated by
+    tests/test_fleet_shard.py). ``topo`` may be a ``Topology``, a
     ``FullyConnected`` marker, or None with a ``schedule``.
     """
 

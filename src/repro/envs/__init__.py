@@ -21,7 +21,7 @@ ENVS = {
 }
 
 # Parameter dimensionality of the synthetic landscape tasks (matches the
-# paper-reduced scale used throughout the benchmarks).
+# paper-reduced scale of examples/paper).
 LANDSCAPE_DIM = 64
 
 
@@ -40,9 +40,9 @@ def resolve_task(task: str):
     (``repro/search``). Memoized per task string: the returned
     ``reward_fn`` closure is a jit-static argument of the fused training
     scans, so a fresh closure per call would miss every executable cache
-    and recompile the scan each run (the fleet/search benches'
-    steady-state compile gates rely on this). ``env``/``policy`` are
-    ``None`` for landscape tasks.
+    and recompile the scan each run (the warm-rerun compile gates of
+    tests/test_train_loop.py and tests/test_search.py rely on this).
+    ``env``/``policy`` are ``None`` for landscape tasks.
     """
     if task.startswith("landscape:"):
         name = task.split(":", 1)[1]

@@ -72,8 +72,8 @@ def make_landscape_reward_fn(name: str, noise_std: float = 0.0) -> Callable:
     Memoized per (name, noise_std): the returned closure is a jit-static
     argument of ``netes_step``/``netes.run`` — a fresh closure per
     training run would miss every jit cache and recompile the fused scan
-    on each ``train_rl_netes`` call (the fleet bench's steady-state
-    compile-count gate relies on this).
+    on each ``train_rl_netes`` call (tests/test_train_loop.py's
+    warm-rerun compile gate relies on this).
     """
     shift = 0.0
     if "@" in name:

@@ -1,3 +1,4 @@
-# Pallas kernels for the compute hot spots, each with a jnp oracle in
-# ref.py. The platform picks the lowering: compiled on TPU, interpreted
-# elsewhere.
+# The one Pallas kernel on a main path (netes_fused_mixing: the int8 wire
+# contraction and broadcast select), and ref.py's jnp oracles that tests
+# compare the live paths against. The platform picks the kernel's
+# lowering: compiled on TPU, interpreted elsewhere.

@@ -1,7 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness contract).
+"""Pure-jnp oracles that tests compare the live paths against.
 
-Each function mirrors its kernel's exact math in straightforward jnp —
-tests sweep shapes/dtypes and ``assert_allclose`` kernel vs oracle.
+Each function states its contraction in straightforward jnp: the Eq. 3
+mixing (dense and neighbor-list) that ``core.netes.mixing_update`` runs,
+the wire-payload contraction and broadcast select of
+``kernels/netes_fused_mixing``, and naive softmax attention for
+``models.attention.blockwise_attention``.
 """
 from __future__ import annotations
 
@@ -88,52 +91,3 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
     return out.reshape(b, sq, h, hd).astype(q.dtype)
-
-
-def mamba_scan_ref(decay, drive):
-    """h_t = decay_t ⊙ h_{t−1} + drive_t, over axis 1 (time).
-    decay, drive: (B, S, D, N) fp32."""
-    def step(h, inp):
-        d, x = inp
-        h = d * h + x
-        return h, h
-
-    dec = decay.swapaxes(0, 1)
-    drv = drive.swapaxes(0, 1)
-    _, hs = jax.lax.scan(step, jnp.zeros_like(decay[:, 0]), (dec, drv))
-    return hs.swapaxes(0, 1)
-
-
-def rwkv6_wkv_ref(r, k, v, w, u, s0=None):
-    """WKV-6 recurrence (matches models.rwkv6.wkv6_scan_ref).
-    r,k,v,w: (B, S, H, n); u: (H, n). Returns (out fp32, final state)."""
-    b, s, h, n = r.shape
-    if s0 is None:
-        s0 = jnp.zeros((b, h, n, n), jnp.float32)
-
-    def step(state, inp):
-        rt, kt, vt, wt = inp
-        kv = kt[..., :, None] * vt[..., None, :]
-        out = jnp.einsum("bhn,bhnm->bhm", rt,
-                         u[None, :, :, None] * kv + state)
-        state = wt[..., :, None] * state + kv
-        return state, out
-
-    xs = tuple(t.swapaxes(0, 1).astype(jnp.float32) for t in (r, k, v, w))
-    s_fin, outs = jax.lax.scan(step, s0, xs)
-    return outs.swapaxes(0, 1), s_fin
-
-
-def moe_topk_ref(logits, k):
-    """Top-k gating: returns (normalized gate values (T, k), expert ids)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    vals, ids = jax.lax.top_k(probs, k)
-    vals = vals / jnp.maximum(vals.sum(axis=-1, keepdims=True), 1e-9)
-    return vals, ids
-
-
-def centered_rank_ref(x):
-    flat = x.reshape(-1)
-    ranks = jnp.argsort(jnp.argsort(flat))
-    return (ranks.astype(jnp.float32) / (flat.shape[0] - 1) - 0.5).reshape(
-        x.shape)

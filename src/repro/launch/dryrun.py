@@ -12,8 +12,7 @@ Usage:
   python -m repro.launch.dryrun --all [--multi-pod] [--out experiments/dryrun]
 
 Emits one JSON per pair with memory_analysis, cost_analysis, per-collective
-byte counts, and the roofline terms (consumed by benchmarks/roofline.py and
-EXPERIMENTS.md §Dry-run/§Roofline).
+byte counts, and the modelled roofline terms (``launch/analysis.py``).
 """
 
 import argparse

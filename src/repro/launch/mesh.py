@@ -7,7 +7,7 @@ import to obtain placeholder devices (see ``dryrun.py``).
 
 Target hardware: TPU v5e pods — 256 chips/pod (16×16), 2 pods for the
 multi-pod config. Peak per chip: 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (constants mirrored in benchmarks/roofline.py).
+~50 GB/s/link ICI (constants mirrored in ``launch/analysis.py``).
 """
 from __future__ import annotations
 
@@ -18,11 +18,3 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(model_parallel: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over whatever devices exist (tests / examples)."""
-    n = len(jax.devices())
-    assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))

@@ -1,9 +1,9 @@
 """Attention: GQA with RoPE; full / sliding-window / chunked-local patterns;
 blockwise (memory-efficient) prefill computation and single-token decode.
 
-The blockwise implementation is the always-on jnp path (compiles on any
-backend, O(block²) memory) — the Pallas ``flash_attention`` kernel in
-``repro.kernels`` is the TPU drop-in validated against the same math.
+The blockwise implementation is the one prefill path (compiles on any
+backend, O(block²) memory); tests check it against the naive oracle
+``kernels.ref.flash_attention_ref``.
 
 Patterns (``kind``):
   * ``full``     — causal.
@@ -23,6 +23,7 @@ from repro.distributed.context import maybe_constrain
 from . import layers
 
 NEG_INF = -1e30
+_PAD_K_POS = 10 ** 9     # position of blockwise_attention's padded keys
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,7 +105,7 @@ def blockwise_attention(spec: AttnSpec, q: jax.Array, k: jax.Array,
     kp = jnp.pad(k, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
     qpos = jnp.pad(q_positions, (0, sq_p - sq), constant_values=-(10 ** 9))
-    kpos = jnp.pad(k_positions, (0, sk_p - sk), constant_values=(10 ** 9))
+    kpos = jnp.pad(k_positions, (0, sk_p - sk), constant_values=_PAD_K_POS)
 
     nq = sq_p // q_block
     nk = sk_p // k_block
@@ -123,6 +124,10 @@ def blockwise_attention(spec: AttnSpec, q: jax.Array, k: jax.Array,
         s = jnp.einsum("bnqhgd,bkhd->bhgnqk", qp, kc,
                        preferred_element_type=jnp.float32) * spec.scale
         bias = _mask_bias(spec, qpos.reshape(-1), kpc, causal)
+        if sk_p > sk:
+            # padded keys sit past every query, which only the causal
+            # mask excludes: mask them for every pattern
+            bias = jnp.where(kpc[None, :] == _PAD_K_POS, NEG_INF, bias)
         s = s + bias.reshape(nq, q_block, -1)[None, None, None]
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])

@@ -2,8 +2,7 @@
 Mamba-1 style blocks).
 
 Prefill/train uses an associative scan over the sequence (O(S log S) depth,
-O(S) work); decode is a single recurrent state update. The Pallas
-``mamba_scan`` kernel in ``repro.kernels`` is the TPU hot-loop drop-in.
+O(S) work); decode is a single recurrent state update.
 
 State-space recurrence (per channel c, state n):
     h_t = exp(Δ_t · A)  ⊙ h_{t−1} + Δ_t · B_t · x_t

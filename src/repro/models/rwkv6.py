@@ -9,7 +9,7 @@ vs RWKV-5's static decay), u the "bonus" for the current token.
 
 Prefill/train runs a chunked lax.scan carrying S (the WKV state is O(H·n²)
 — independent of sequence length, hence `long_500k` eligibility); decode is
-a single state update. The Pallas ``rwkv6_wkv`` kernel is the TPU hot-loop.
+a single state update.
 
 Token-shift (the RWKV "half-channel looks at t−1") is implemented with
 jnp.pad/shift; the LoRA-style low-rank adapters produce the per-token

@@ -1,8 +1,7 @@
 """Structured JSONL run traces (DESIGN.md §15).
 
 One trace = one append-only JSONL file. Line 1 is a schema-versioned
-``meta`` record (mirroring the bench registry's ``schema`` discipline);
-every following line is a ``span`` or ``event`` record:
+``meta`` record; every following line is a ``span`` or ``event`` record:
 
     {"kind": "meta", "schema": "repro.trace/v1", "name": ..., env...}
     {"kind": "span", "name": "chunk", "t0": ..., "dur_s": ...,

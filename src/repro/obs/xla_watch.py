@@ -1,8 +1,6 @@
 """XLA activity counters — compiles and host transfers (DESIGN.md §15).
 
-Promoted from ``benchmarks/common.py`` so the trace layer and the test
-suite share one implementation (``benchmarks.common`` re-exports
-``count_backend_compiles`` for existing call sites).
+The trace layer and the test suite share this one implementation.
 
 Two kinds of hook:
 
@@ -71,8 +69,8 @@ def _remove_transfer_callback(cb) -> None:
 @contextlib.contextmanager
 def count_backend_compiles() -> Iterator[List[str]]:
     """Yields a list that grows by one per XLA backend compilation —
-    the fleet bench's steady-state gate (a warmed run must replay with
-    ZERO compiles; scheduled topologies must match static runs)."""
+    the tests' steady-state gate (a warmed run must replay with ZERO
+    compiles; scheduled topologies must match static runs)."""
     counts: List[str] = []
 
     def cb(event, *a, **kw):

@@ -194,7 +194,7 @@ def _make_plans(pool: Sequence[CandidateSpec], representation: str
 
 # ---------------------------------------------------------------------------
 # the batched round programs (module-level jits — cached across rounds,
-# tournaments, and the bench's warm-up/timed replay)
+# tournaments, and a warmed tournament's replay)
 # ---------------------------------------------------------------------------
 
 def _eval_score(state, key, reward_fn, episodes: int):

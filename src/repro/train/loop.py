@@ -62,8 +62,8 @@ class TrainConfig:
     # the idealized (channel-free) path, bit-identical to "lossless".
     channel: Optional[Union[ChannelSpec, str]] = None
     # Fused wire-form dispatch for quantizing channels (DESIGN.md §12).
-    # False pins the legacy decode-then-contract path — the benches'
-    # unfused control legs; semantics are identical either way.
+    # False pins the legacy decode-then-contract path — the tests'
+    # unfused control runs; semantics are identical either way.
     channel_fused: bool = True
     # Shard the agent axis over this many devices (DESIGN.md §13): the
     # fused scans route through distributed.fleet_shard with halo /
@@ -404,8 +404,7 @@ def train_rl_netes(task: str, tc: TrainConfig,
     history["max_eval"] = max(history["eval"]) if history["eval"] else None
     if channel is not None:
         # realized (not modeled) traffic: messages that actually moved ×
-        # the pipeline's encoded bytes per message — the resilience
-        # bench's regression-gated metric (DESIGN.md §11).
+        # the pipeline's encoded bytes per message (DESIGN.md §11).
         total_msgs = float(np.sum(history["msgs"], dtype=np.float64))
         history["realized_msgs"] = total_msgs
         history["realized_wire_bytes"] = int(

@@ -2,7 +2,7 @@ import jax
 import numpy as np
 import pytest
 
-# NOTE: do NOT set XLA_FLAGS device-count here — smoke tests and benches
+# NOTE: do NOT set XLA_FLAGS device-count here — smoke tests
 # must see the single real CPU device (the 512-device override belongs to
 # repro.launch.dryrun ONLY).
 

@@ -3,8 +3,11 @@
 Property tests (via the hypothesis shim) for the codec algebra, exact
 lossless/dropout(0) parity on every physical representation (static AND
 scheduled), event-trigger semantics, realized-traffic counters, the
-distributed step builders, and bit-for-bit channel-state resume through
-``checkpoint/io`` (mirroring the schedule resume test).
+distributed step builders, bit-for-bit channel-state resume through
+``checkpoint/io`` (mirroring the schedule resume test), and the
+topology × channel training grid: one scan per cell, realized bytes
+equal to the live-edge count, fused ≡ unfused wire paths, and sparse ER
+moving a fraction of fully-connected's bytes.
 """
 import dataclasses
 
@@ -13,13 +16,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
+from _train_rerun import assert_one_scan_per_config, rerun_after_warmup
 
 from repro.comm import channel as cc
 from repro.comm.channel import ChannelSpec
 from repro.core import netes, topology, topology_repr
 from repro.core.netes import NetESConfig
 from repro.core.topology import TopologySpec
-from repro.train.loop import TrainConfig, train_rl_netes
+from repro.envs import resolve_task
+from repro.train.loop import TrainConfig, build_topology, train_rl_netes
 
 N = 12
 DIM = 6
@@ -401,3 +406,102 @@ def test_grid_crosses_channels_and_collapses_lossless():
     labels = [c.label() for c in grid]
     assert labels == ["erdos_renyi:p=0.2:s=0", "erdos_renyi:p=0.2:s=0+q8",
                       "fully_connected", "fully_connected+q8"]
+
+
+# ---------------------------------------------------------------------------
+# the topology × channel training grid
+# ---------------------------------------------------------------------------
+
+GRID_N, GRID_P, GRID_ITERS = 64, 0.1, 8
+GRID_TASK = "landscape:rastrigin@2.5"
+GRID_FAMILIES = {"erdos_renyi": (GRID_P, "sparse"),
+                 "fully_connected": (1.0, "dense")}
+GRID_CHANNELS = {"lossless": "lossless", "drop10": "dropout(p=0.1,seed=0)",
+                 "drop30": "dropout(p=0.3,seed=0)", "q8": "quantize(bits=8)",
+                 "q4": "quantize(bits=4)", "q1": "quantize(bits=1)"}
+DROP_P = {"drop10": 0.1, "drop30": 0.3}
+
+
+def _grid_tc(family, suffix, fused=True):
+    p, rep = GRID_FAMILIES[family]
+    return TrainConfig(
+        n_agents=GRID_N, iters=GRID_ITERS,
+        topology=TopologySpec(family=family, n_agents=GRID_N, p=p, seed=0),
+        representation=rep, channel=GRID_CHANNELS[suffix],
+        channel_fused=fused, seed=0, eval_every=GRID_ITERS // 2,
+        eval_episodes=2,
+        # every iteration broadcasts, so each step's message count is
+        # known: the live mixing edges plus N broadcast receipts
+        netes=NetESConfig(alpha=0.05, sigma=0.1, p_broadcast=1.0))
+
+
+@pytest.fixture(scope="module")
+def grid_cell(tmp_path_factory):
+    """Runs (and keeps) one cell: a warm run and its rerun, the rerun's
+    history, both runs' spans, and the graph's directed non-self edges."""
+    done = {}
+
+    def cell(family, suffix):
+        if (family, suffix) not in done:
+            tc = _grid_tc(family, suffix)
+            topo = build_topology(tc)
+            adj = np.asarray(topo.to_dense())
+            edges = int((adj != 0).sum() - GRID_N)
+            hist, warm, rerun = rerun_after_warmup(
+                GRID_TASK, tc, tmp_path_factory.mktemp(f"{family}-{suffix}"))
+            done[family, suffix] = dict(topo=topo, edges=edges, hist=hist,
+                                        warm=warm, rerun=rerun)
+        return done[family, suffix]
+    return cell
+
+
+@pytest.mark.parametrize("suffix", list(GRID_CHANNELS))
+@pytest.mark.parametrize("family", list(GRID_FAMILIES))
+def test_channel_grid_cell(grid_cell, family, suffix):
+    """One scan per cell; realized messages are the live edges plus the
+    broadcast receipts of every step, and the bytes are those messages
+    × the channel's encoded message size."""
+    c = grid_cell(family, suffix)
+    assert c["topo"].kind == GRID_FAMILIES[family][1]
+    assert_one_scan_per_config(c["warm"], c["rerun"])
+    hist, edges = c["hist"], c["edges"]
+    msgs = np.asarray(hist["msgs"], np.float64)
+    assert msgs.shape == (GRID_ITERS,)
+    live = msgs - GRID_N
+    if suffix in DROP_P:
+        # symmetric drops remove edges in pairs; about p of them go
+        assert (live % 2 == 0).all() and (live <= edges).all()
+        kept = live.sum() / (GRID_ITERS * edges)
+        assert abs(kept - (1.0 - DROP_P[suffix])) < 0.08, kept
+    else:
+        np.testing.assert_array_equal(live, edges)
+    channel = cc.compile_channel(GRID_CHANNELS[suffix], GRID_N)
+    dim = resolve_task(GRID_TASK)[1]
+    assert hist["realized_msgs"] == msgs.sum()
+    assert hist["realized_wire_bytes"] == round(
+        msgs.sum() * channel.payload_bytes(dim))
+
+
+@pytest.mark.parametrize("suffix", ["q8", "q4", "q1"])
+def test_channel_grid_fused_matches_unfused(grid_cell, suffix, tmp_path):
+    """The sparse ER cells' wire path trains exactly as the fake-quant
+    path does, and moves the same bytes; the fake-quant control is one
+    scan per config too."""
+    fused = grid_cell("erdos_renyi", suffix)["hist"]
+    unfused, warm, rerun = rerun_after_warmup(
+        GRID_TASK, _grid_tc("erdos_renyi", suffix, fused=False), tmp_path)
+    assert_one_scan_per_config(warm, rerun)
+    assert unfused["eval"] == fused["eval"]
+    assert unfused["realized_wire_bytes"] == fused["realized_wire_bytes"]
+
+
+def test_er_moves_under_2p_of_fc_bytes(grid_cell):
+    """Summed over the lossy channels, sparse ER moves less than 2·p of
+    fully-connected's realized bytes: the counters follow the graph."""
+    lossy = [s for s in GRID_CHANNELS if s != "lossless"]
+
+    def total(family):
+        return sum(grid_cell(family, s)["hist"]["realized_wire_bytes"]
+                   for s in lossy)
+
+    assert total("erdos_renyi") < 2 * GRID_P * total("fully_connected")

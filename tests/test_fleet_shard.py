@@ -360,6 +360,7 @@ from repro.core import netes, topology, topology_repr
 from repro.core.netes import NetESConfig
 from repro.core.topology import TopologySpec
 from repro.distributed import fleet_shard
+from repro.obs import count_backend_compiles
 from repro.train.loop import TrainConfig, train_rl_netes
 
 out = {}
@@ -434,6 +435,25 @@ text = eng._run_impl.lower(
     num_iters=2).compile().as_text()
 out["u32_shapes"] = sorted(set(re.findall(r"u32\[(\d+),(\d+)\]", text)))
 out["n_loc"] = eng.plan.n_loc
+
+# each exchange on the 4-device mesh: its payload bytes per shard and
+# step, and what a second run of the built engine compiles
+circ = topology_repr.from_dense(
+    topology.circulant_from_offsets(n, [1, 2, 3, 4]), "circulant")
+q8 = comm_channel.compile_channel("quantize(bits=8)", n)
+out["payload_bytes"], out["replay_compiles"] = {}, {}
+for name, t, ch in (("circulant", circ, None), ("er_sparse_q8", topo, q8),
+                    ("er_sparse", topo, None),
+                    ("fc", fleet_shard.FullyConnected(n), None)):
+    eng = fleet_shard.ShardedNetES(
+        t, lambda p, k: -(p * p).sum(axis=-1), NetESConfig(),
+        mesh=fleet_shard.build_mesh(4), channel=ch)
+    out["payload_bytes"][name] = eng.collective_bytes(d)["payload_bytes"]
+    cs = ch.init(state.thetas) if ch is not None else None
+    jax.block_until_ready(eng.run(state, 2, chan_state=cs))
+    with count_backend_compiles() as replay:
+        jax.block_until_ready(eng.run(state, 2, chan_state=cs))
+    out["replay_compiles"][name] = len(replay)
 print("FOUR_DEVICES " + json.dumps(out))
 """
 
@@ -503,3 +523,24 @@ def test_each_shard_draws_only_its_own_noise_rows(four_devices):
     assert n_loc == 16
     assert (n_loc, 24) in shapes
     assert (64, 24) not in shapes, shapes
+
+
+@pytest.mark.parametrize("cheaper,dearer", [("circulant", "er_sparse_q8"),
+                                            ("er_sparse_q8", "er_sparse"),
+                                            ("er_sparse", "fc")])
+def test_sharded_payload_bytes_follow_the_graph(four_devices, cheaper,
+                                                dearer):
+    """On a 4-device mesh a ring of 4 offsets moves fewer payload bytes
+    than the int8 ER halo, the int8 codec shrinks the f32 ER halo, and
+    the ER halo undercuts the fully-connected gather."""
+    b = four_devices["payload_bytes"]
+    assert 0 < b[cheaper] < b[dearer], b
+
+
+@pytest.mark.parametrize("exchange", ["circulant", "er_sparse_q8",
+                                      "er_sparse", "fc"])
+def test_sharded_replay_compiles_nothing(four_devices, exchange):
+    """A built engine's second run on the 4-device mesh replays the
+    program its first run compiled, for every exchange: halo rounds of
+    a ring or of ER neighbors, int8 or f32, and the full gather."""
+    assert four_devices["replay_compiles"][exchange] == 0

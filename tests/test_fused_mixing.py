@@ -278,6 +278,7 @@ def test_wire_dispatch_respects_edge_mask(rep):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("spec", ["quantize(bits=8)",
+                                  "quantize(bits=4)",
                                   "quantize(bits=1)",
                                   "quantize(bits=4)|dropout(p=0.2,seed=3)"])
 def test_netes_run_fused_matches_unfused_bitwise(spec):

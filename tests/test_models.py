@@ -13,24 +13,42 @@ from repro.models import attention, mamba, moe, rwkv6, transformer
 RNG = np.random.default_rng(7)
 
 
-def test_blockwise_attention_matches_naive():
+# (kind, causal) of AttnSpec for each mask of flash_attention_ref, and
+# the pattern's width: window for sliding, chunk for chunked.
+ATTN_MASKS = {"causal": ("full", True, 0), "window": ("sliding", True, 96),
+              "chunk": ("chunked", True, 128), "full": ("full", False, 0)}
+
+
+@pytest.mark.parametrize("b,s,h,kv,hd,mask,dtype,block", [
+    *[(*shape, mask, jnp.float32, 128)
+      for shape in [(1, 128, 4, 4, 32),       # MHA
+                    (2, 257, 4, 2, 64),       # GQA, ragged seq
+                    (1, 512, 8, 1, 32)]       # MQA
+      for mask in ATTN_MASKS],
+    (1, 256, 4, 2, 64, "causal", jnp.bfloat16, 128),
+    (2, 200, 4, 2, 32, "window", jnp.float32, 64),  # 200 ∤ 64
+])
+def test_blockwise_attention_matches_naive(b, s, h, kv, hd, mask, dtype,
+                                           block):
     from repro.kernels import ref
-    b, s, h, kv, hd = 2, 200, 4, 2, 32
-    q = jnp.asarray(RNG.normal(size=(b, s, h, hd)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(b, s, kv, hd)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(b, s, kv, hd)), jnp.float32)
+    kind, causal, width = ATTN_MASKS[mask]
+    q = jnp.asarray(RNG.normal(size=(b, s, h, hd)), dtype)
+    k = jnp.asarray(RNG.normal(size=(b, s, kv, hd)), dtype)
+    v = jnp.asarray(RNG.normal(size=(b, s, kv, hd)), dtype)
     pos = jnp.arange(s)
-    for kind, window in [("full", 0), ("sliding", 48), ("chunked", 64)]:
-        spec = attention.AttnSpec(num_heads=h, num_kv_heads=kv, head_dim=hd,
-                                  kind=kind, window=window)
-        o_b = attention.blockwise_attention(spec, q, k, v, pos, pos,
-                                            q_block=64, k_block=64)
-        o_r = ref.flash_attention_ref(
-            q, k, v, causal=True,
-            window=window if kind == "sliding" else 0,
-            chunk=window if kind == "chunked" else 0)
-        np.testing.assert_allclose(np.asarray(o_b), np.asarray(o_r),
-                                   rtol=3e-5, atol=3e-5, err_msg=kind)
+    spec = attention.AttnSpec(num_heads=h, num_kv_heads=kv, head_dim=hd,
+                              kind=kind, window=width)
+    o_b = attention.blockwise_attention(spec, q, k, v, pos, pos,
+                                        causal=causal, q_block=block,
+                                        k_block=block)
+    o_r = ref.flash_attention_ref(
+        q, k, v, causal=causal,
+        window=width if kind == "sliding" else 0,
+        chunk=width if kind == "chunked" else 0)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 3e-5
+    np.testing.assert_allclose(np.asarray(o_b, np.float32),
+                               np.asarray(o_r, np.float32),
+                               rtol=tol, atol=tol)
 
 
 def test_decode_attention_matches_prefill():
@@ -97,6 +115,48 @@ def test_mamba_chunked_matches_unchunked_and_decode():
                                rtol=1e-4, atol=1e-5)
 
 
+def _sequential_recurrence(decay, drive):
+    """h_t = decay_t ⊙ h_{t−1} + drive_t over axis 1, one step at a time."""
+    dec, drv = np.asarray(decay, np.float64), np.asarray(drive, np.float64)
+    h = np.zeros_like(dec[:, 0])
+    out = np.empty_like(dec)
+    for t in range(dec.shape[1]):
+        h = dec[:, t] * h + drv[:, t]
+        out[:, t] = h
+    return out
+
+
+@pytest.mark.parametrize("b,s,d,n", [(1, 32, 64, 8), (2, 64, 300, 16),
+                                     (1, 128, 512, 4),
+                                     (2, 63, 32, 8)])  # odd: uneven halving
+def test_mamba_scan_matches_sequential_recurrence(b, s, d, n):
+    """The associative scan ``mamba_block`` runs equals the recurrence
+    stepped once per token."""
+    dec = jnp.asarray(RNG.uniform(0.8, 0.999, (b, s, d, n)), jnp.float32)
+    drv = jnp.asarray(RNG.normal(size=(b, s, d, n)), jnp.float32)
+    np.testing.assert_allclose(np.asarray(mamba.mamba_scan_ref(dec, drv)),
+                               _sequential_recurrence(dec, drv),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("b,s,h,n,chunk", [(1, 16, 2, 8, 8),
+                                           (2, 48, 3, 16, 16),
+                                           (1, 64, 4, 32, 16),
+                                           (1, 40, 2, 16, 16)])  # 16 ∤ 40
+def test_wkv6_chunked_matches_scan(b, s, h, n, chunk):
+    r = jnp.asarray(RNG.normal(size=(b, s, h, n)), jnp.float32)
+    k = jnp.asarray(RNG.normal(size=(b, s, h, n)), jnp.float32)
+    v = jnp.asarray(RNG.normal(size=(b, s, h, n)), jnp.float32)
+    w = jnp.asarray(RNG.uniform(0.92, 0.999, (b, s, h, n)), jnp.float32)
+    u = jnp.asarray(RNG.normal(size=(h, n)), jnp.float32)
+    o_c, s_c = rwkv6.wkv6_chunked(r, k, v, w, u, chunk=chunk)
+    o_r, s_r = rwkv6.wkv6_scan_ref(r, k, v, w, u)
+    np.testing.assert_allclose(np.asarray(o_c), np.asarray(o_r),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(s_c), np.asarray(s_r),
+                               rtol=2e-4, atol=2e-4)
+
+
 def test_rwkv_block_decode_matches_prefill():
     spec = rwkv6.RWKV6Spec(d_model=64, num_heads=2)
     p = rwkv6.rwkv6_init(jax.random.PRNGKey(0), spec, jnp.float32)
@@ -113,13 +173,22 @@ def test_rwkv_block_decode_matches_prefill():
                                rtol=5e-4, atol=5e-4)
 
 
-def test_moe_block_matches_dense_ref_no_drops():
-    spec = moe.MoESpec(num_experts=4, experts_per_token=2, d_model=32,
-                       d_ff=64, capacity_factor=8.0, group_size=64)
+@pytest.mark.parametrize("b,t,e,k,d,group", [
+    (2, 128, 4, 2, 32, 64),
+    (1, 100, 8, 2, 16, 100), (1, 500, 16, 6, 16, 500),
+    (1, 64, 64, 8, 16, 64), (1, 257, 128, 1, 16, 257)])
+def test_moe_block_matches_dense_ref_no_drops(b, t, e, k, d, group):
+    """A capacity factor of E gives every expert room for all of a
+    group's choices, so nothing drops."""
+    spec = moe.MoESpec(num_experts=e, experts_per_token=k, d_model=d,
+                       d_ff=2 * d, capacity_factor=float(e),
+                       group_size=group)
     p = moe.moe_init(jax.random.PRNGKey(1), spec, jnp.float32)
-    x = jnp.asarray(RNG.normal(size=(2, 128, 32)), jnp.float32)
-    np.testing.assert_allclose(np.asarray(moe.moe_block(p, spec, x)),
-                               np.asarray(moe.moe_ref(p, spec, x)),
+    x = jnp.asarray(RNG.normal(size=(b, t, d)), jnp.float32)
+    block, dense = (jax.jit(f, static_argnums=1)
+                    for f in (moe.moe_block, moe.moe_ref))
+    np.testing.assert_allclose(np.asarray(block(p, spec, x)),
+                               np.asarray(dense(p, spec, x)),
                                rtol=2e-4, atol=2e-4)
 
 

@@ -14,6 +14,7 @@ from repro.core.netes import NetESConfig
 from repro.core.topology import TopologySpec
 from repro.core.topology_sched import ScheduleSpec
 from repro.envs import make_landscape_reward_fn
+from repro.obs import count_backend_compiles
 from repro.search import (CandidateSpec, SearchConfig, make_grid,
                           prior_scores, run_search, seed_pool)
 from repro.search.tournament import (_eval_score, _make_plans,
@@ -194,9 +195,22 @@ _SC = SearchConfig(
     eval_episodes=1, seed=0, netes=CFG)
 
 
-def test_successive_halving_deterministic_and_shrinking():
-    r1 = run_search("landscape:rastrigin@2.5", _SC)
-    r2 = run_search("landscape:rastrigin@2.5", _SC)
+# A mixed-family pool: ER, small-world and the fully-connected control
+# over three densities and two graph seeds.
+_MIXED_SC = dataclasses.replace(
+    _SC, n_agents=32, families=("erdos_renyi", "small_world",
+                                "fully_connected"),
+    densities=(0.05, 0.1, 0.2), seeds=(0, 1), pool_size=5, round_iters=6)
+
+
+@pytest.mark.parametrize("sc", [_SC, _MIXED_SC], ids=["er_fc", "mixed"])
+def test_successive_halving_deterministic_and_shrinking(sc):
+    """A warmed tournament replays its history exactly and compiles
+    nothing: each round is one program per cohort shape."""
+    r1 = run_search("landscape:rastrigin@2.5", sc)
+    with count_backend_compiles() as compiles:
+        r2 = run_search("landscape:rastrigin@2.5", sc)
+    assert compiles == []
     assert r1.history == r2.history
     assert r1.winner == r2.winner and r1.score == r2.score
     sizes = [len(h["scores"]) for h in r1.history]

@@ -9,6 +9,7 @@ import pytest
 
 from repro.core import netes, topology, topology_repr
 from repro.core.netes import NetESConfig
+from repro.kernels import ref as ref_kernels
 
 SIZES = [8, 64, 257]
 FAMILIES = topology.available_families()
@@ -73,6 +74,84 @@ def test_auto_representation_is_parity_preserving(family):
     out = netes.mixing_update(topo, th, pe, sh, cfg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Eq. 3 through mixing_update against the jnp oracles of kernels/ref.py
+# ---------------------------------------------------------------------------
+
+SIGMA = 0.1
+
+
+def _oracle_inputs(n, dim):
+    """θ, ε and shaped rewards, plus the perturbed θ + σε the live path
+    takes."""
+    th = jnp.asarray(RNG.normal(size=(n, dim)), jnp.float32)
+    ep = jnp.asarray(RNG.normal(size=(n, dim)), jnp.float32)
+    sh = jnp.asarray(RNG.normal(size=n), jnp.float32)
+    return th, ep, sh, th + SIGMA * ep
+
+
+def _unit_scale_update(topo, th, pert, sh):
+    """mixing_update with α = Nσ², so its 1/(Nσ²) scale is 1 and the
+    update is the bare Eq. 3 contraction the oracles compute."""
+    n = th.shape[0]
+    cfg = NetESConfig(alpha=n * SIGMA ** 2, sigma=SIGMA)
+    return netes.mixing_update(topo, th, pert, sh, cfg)
+
+
+def _oracle_graph(rep, n, p):
+    """A symmetric graph with self-loops the representation can hold:
+    a scattered one for dense/sparse, a circulant-ER ring otherwise."""
+    if rep == "circulant":
+        return topology.circulant_erdos_renyi(n, p=p, seed=n)
+    adj = (RNG.random((n, n)) < p).astype(np.float32)
+    adj = np.maximum(adj, adj.T)
+    np.fill_diagonal(adj, 1.0)
+    return adj
+
+
+@pytest.mark.parametrize("n,dim", [(8, 64), (16, 700), (32, 1024), (5, 33)])
+@pytest.mark.parametrize("rep", ["dense", "circulant"])
+def test_dense_mixing_matches_oracle(n, dim, rep):
+    adj = _oracle_graph(rep, n, 0.5)
+    th, ep, sh, pert = _oracle_inputs(n, dim)
+    out = _unit_scale_update(topology_repr.from_dense(adj, rep), th, pert,
+                             sh)
+    want = ref_kernels.netes_mixing_ref(jnp.asarray(adj), sh, sh, th, ep,
+                                        sigma=SIGMA)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("n,dim,p", [(8, 64, 0.3), (16, 700, 0.1),
+                                     (32, 1024, 0.2), (5, 33, 0.5)])
+@pytest.mark.parametrize("rep", ["sparse", "circulant"])
+def test_sparse_mixing_matches_oracle(n, dim, p, rep):
+    adj = _oracle_graph(rep, n, p)
+    lists = topology_repr.from_dense(adj, "sparse")
+    th, ep, sh, pert = _oracle_inputs(n, dim)
+    out = _unit_scale_update(topology_repr.from_dense(adj, rep), th, pert,
+                             sh)
+    want = ref_kernels.sparse_mixing_ref(lists.neighbor_idx,
+                                         lists.neighbor_mask, sh, sh, th,
+                                         ep, sigma=SIGMA)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_sparse_mixing_equals_dense_at_width():
+    """The neighbor-list path restricted to the graph's edges equals the
+    dense path on the same graph, at a width of several lanes."""
+    n, dim = 16, 384
+    adj = _oracle_graph("sparse", n, 0.25)
+    th, _, sh, pert = _oracle_inputs(n, dim)
+    out_s = _unit_scale_update(topology_repr.from_dense(adj, "sparse"),
+                               th, pert, sh)
+    out_d = _unit_scale_update(topology_repr.from_dense(adj, "dense"),
+                               th, pert, sh)
+    np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_d),
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_sparse_preserves_edge_weights():

@@ -9,15 +9,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# single home for the compile counter (pokes private jax monitoring —
-# keep one copy so a jax upgrade can't silently break just one of the
-# bench gate and this test)
-from benchmarks.common import count_backend_compiles
 from repro.core import netes, topology, topology_repr, topology_sched
 from repro.core.netes import NetESConfig
 from repro.core.topology import TopologySpec
 from repro.core.topology_sched import ScheduleSpec
 from repro.envs import make_landscape_reward_fn
+from repro.obs import count_backend_compiles
 
 
 # ---------------------------------------------------------------------------

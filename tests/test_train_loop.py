@@ -1,15 +1,19 @@
 """TrainConfig construction contract + train_rl_netes eval-protocol
-bookkeeping, and the evaluation program built once per run."""
+bookkeeping, the evaluation program built once per run, and a warmed
+run's replay building no training program for any representation,
+schedule or quantized channel."""
 import json
 
 import jax
 import numpy as np
 import pytest
+from _train_rerun import assert_one_scan_per_config, rerun_after_warmup
 
 from repro.core.netes import NetESConfig
 from repro.core.topology import TopologySpec
 from repro.core.topology_sched import ScheduleSpec
-from repro.train.loop import TrainConfig, train_rl_netes
+from repro.train.loop import (TrainConfig, build_channel, build_schedule,
+                              build_topology, train_rl_netes)
 
 
 # ---------------------------------------------------------------------------
@@ -174,3 +178,65 @@ def test_build_span_records_the_reward_blocks(tmp_path):
     assert len(builds) == 1
     attrs = builds[0]["attrs"]
     assert (attrs["reward_blocks"], attrs["reward_block_rows"]) == (1, n)
+
+
+# ---------------------------------------------------------------------------
+# a warmed run replays without building a training program
+# ---------------------------------------------------------------------------
+
+N_FLEET, P_FLEET = 64, 0.1
+TASK = "landscape:rastrigin"
+
+
+def _fleet_tc(family, representation, iters=8, **extra):
+    return TrainConfig(
+        n_agents=N_FLEET, iters=iters,
+        topology=TopologySpec(family=family, n_agents=N_FLEET, p=P_FLEET,
+                              seed=0),
+        representation=representation, seed=0, eval_every=iters // 2,
+        eval_episodes=2,
+        netes=NetESConfig(alpha=0.05, sigma=0.1, p_broadcast=0.8), **extra)
+
+
+@pytest.mark.parametrize("family,representation,extra", [
+    ("erdos_renyi", "dense", {}),
+    ("erdos_renyi", "sparse", {}),
+    ("circulant_erdos_renyi", "circulant", {}),
+    ("circulant_erdos_renyi", "circulant",
+     {"schedule": "rotate_circulant(stride=1)"}),
+    ("erdos_renyi", "sparse", {"schedule": "resample_er(period=2)"}),
+    ("erdos_renyi", "dense",
+     {"schedule": "anneal_density(p_end=0.02,horizon=8)"}),
+    ("erdos_renyi", "sparse", {"channel": "quantize(bits=8)"}),
+    ("erdos_renyi", "sparse", {"channel": "quantize(bits=4)"}),
+    ("erdos_renyi", "sparse", {"channel": "quantize(bits=1)"}),
+    ("erdos_renyi", "sparse", {"probes": "fitness|consensus|graph"}),
+], ids=["dense", "sparse", "circulant", "rotate_circulant", "resample_er",
+        "anneal_density", "q8", "q4", "q1", "probed"])
+def test_warm_rerun_builds_no_training_program(family, representation,
+                                               extra, tmp_path):
+    """The run gets the representation it asked for (a schedule's first
+    graph included, and the fused wire path for a quantized channel),
+    and it builds one scan: no retrace per chunk, graph redraw, wire
+    payload or probe ring, and none when the warmed config runs
+    again."""
+    tc = _fleet_tc(family, representation, **extra)
+    sched = build_schedule(tc)
+    topo = build_topology(tc) if sched is None else sched.init().topo
+    assert topo.kind == representation
+    if tc.channel is not None:
+        assert build_channel(tc).wire_fused(topo)
+    hist, warm, rerun = rerun_after_warmup(TASK, tc, tmp_path)
+    assert_one_scan_per_config(warm, rerun)
+    assert len(hist["eval"]) == 2 and np.isfinite(hist["eval"]).all()
+
+
+def test_dense_and_sparse_training_agree():
+    """The same ER graph and seeds through the dense and the neighbor-list
+    representation follow the same training trajectory."""
+    finals = [train_rl_netes(TASK, _fleet_tc("erdos_renyi", rep))
+              for rep in ("dense", "sparse")]
+    dense, sparse = (h["final_eval"] for h in finals)
+    assert abs(dense - sparse) <= 1e-3 * max(1.0, abs(dense))
+    np.testing.assert_allclose(finals[0]["reward_mean"],
+                               finals[1]["reward_mean"], rtol=1e-4)
